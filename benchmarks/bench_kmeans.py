@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import BufferPool
 from repro.core.attributes import AttributeSet, DurabilityType
 from repro.core.services import SequentialWriter, get_page_iterators
@@ -121,4 +122,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     run()

@@ -106,11 +106,15 @@ def _best_of(backend: str, label: str, *, repeats: int) -> float:
     driver heap the earlier benchmarks fattened makes every proc-backend
     fork pay COW faults, and skews the in-process allocator too."""
     best = None
+    # The measurement runs cluster code only, which is numpy on the host.
+    # JAX_PLATFORMS=cpu keeps the child off the TPU even if something it
+    # imports ever touched JAX: the parent may already hold the chip.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     for _ in range(repeats):
         out = subprocess.run(
             [sys.executable, "-m", "benchmarks.bench_procplane",
              "--rep", label, backend],
-            capture_output=True, text=True, check=True)
+            capture_output=True, text=True, check=True, env=env)
         elapsed = None
         for line in out.stdout.splitlines():
             if line.startswith("ELAPSED "):

@@ -26,6 +26,8 @@ from typing import List
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
+
 from .common import record, smoke_mode
 
 # v1: overcap rows (serving/cluster4node/overcap/{admission_on,always_grant,
@@ -222,6 +224,7 @@ def main(argv=None) -> None:
     args = parser.parse_args(argv)
     if args.smoke:
         os.environ["BENCH_SMOKE"] = "1"
+    configure_compile_cache()
     print("name,us_per_call,derived")
     run(args.json_out)
 

@@ -35,9 +35,12 @@ def main(argv=None) -> None:
     if args.smoke:
         os.environ["BENCH_SMOKE"] = "1"
 
+    from repro.compile_cache import configure_compile_cache
     from . import (bench_join, bench_procplane, bench_recovery,
                    bench_serving, bench_shuffle)
     from .common import write_results_json
+
+    configure_compile_cache()
 
     print("name,us_per_call,derived")
     if args.suite == "all":

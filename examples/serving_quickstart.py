@@ -23,13 +23,13 @@ def main() -> None:
     cluster = Cluster(num_nodes=4, node_capacity=8 << 20,
                       page_size=1 << 14, replication_factor=1,
                       admission=True)
-    # 4 HBM slots + a 2 KiB host budget per shard: a long sequence pushes
-    # slabs through all three spill levels
-    tier = ServingTier(cluster, hbm_pages_per_node=4,
-                       host_budget_bytes=2048)
+    # 8 HBM slots + a one-slab (512 B) host budget per shard: the long
+    # sequence pushes slabs through all three spill levels
+    tier = ServingTier(cluster, hbm_pages_per_node=8,
+                       host_budget_bytes=512)
 
     # --- continuous-batching admission -------------------------------------
-    plan = tier.admit({1: 10, 2: 6, 3: 8})
+    plan = tier.admit({1: 30, 2: 6, 3: 8})
     homes = {s: sess.node for s, sess in sorted(tier.sessions.items())}
     print(f"admitted 3 sequences; session homes {homes}, "
           f"{len(plan.diversions)} diverted off pressured nodes")
@@ -56,7 +56,9 @@ def main() -> None:
           f"byte-identical, all sequences verify against the KV oracle")
 
     # --- attention over the restored pool ----------------------------------
-    out = tier.attend([1, 2, 3], impl="xla")
+    # a shard attends over what its HBM pool holds at once: seq 1 alone
+    # outgrows its pool, so attend over the two short sequences
+    out = tier.attend([2, 3])     # the Pallas kernel, on each pool's device
     print(f"decode attention over the serving pool: "
           f"{sorted((s, v.shape) for s, v in out.items())}")
 

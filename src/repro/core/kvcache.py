@@ -95,11 +95,12 @@ class PagedKVCache:
     Physical layout (device): ``kv[L, P, page_size, 2, kv_heads, head_dim]``
     where P = hbm_pages. Logical pages beyond P live in the host store.
     ``block_table(seq)`` yields physical slots for the attention kernel.
+    ``device`` is the chip that holds the pool (None: JAX's default device).
     """
 
     def __init__(self, num_layers: int, hbm_pages: int, page_size: int,
                  kv_heads: int, head_dim: int, dtype=np.float32,
-                 host_store: Optional[HostSlabStore] = None):
+                 host_store: Optional[HostSlabStore] = None, device=None):
         import jax.numpy as jnp  # local import: keep module importable w/o jax
         self.num_layers = num_layers
         self.hbm_pages = hbm_pages
@@ -107,8 +108,10 @@ class PagedKVCache:
         self.kv_heads = kv_heads
         self.head_dim = head_dim
         self.dtype = dtype
+        self.device = device
         self.kv = jnp.zeros(
-            (num_layers, hbm_pages, page_size, 2, kv_heads, head_dim), dtype=dtype)
+            (num_layers, hbm_pages, page_size, 2, kv_heads, head_dim),
+            dtype=dtype, device=device)
         self._free_slots: List[int] = list(range(hbm_pages))[::-1]
         self.paging = PagingSystem()
         self.clock = 1
@@ -174,7 +177,7 @@ class PagedKVCache:
         page.offset = None
 
     def _restore(self, page: Page, ls: LocalitySet) -> int:
-        import jax.numpy as jnp
+        import jax
         slot = self._alloc_slot(exclude_set=ls.name)
         try:
             slab = self.host_store.take(page.page_id)
@@ -184,7 +187,8 @@ class PagedKVCache:
             self._free_slots.append(slot)
             raise
         if slab is not None:
-            self.kv = self.kv.at[:, slot].set(jnp.asarray(slab))
+            self.kv = self.kv.at[:, slot].set(
+                jax.device_put(slab, self.device))
             self.stats["fetches"] += 1
         page.offset = slot
         return slot
@@ -238,7 +242,7 @@ class PagedKVCache:
     def write_page(self, seq_id: int, page_index: int, slab: np.ndarray) -> None:
         """Overwrite one logical page's slab ([L, page, 2, KH, D]); restores
         the page to HBM first if it was offloaded."""
-        import jax.numpy as jnp
+        import jax
         st = self._seqs[seq_id]
         ls = self._sets[seq_id]
         page = self._pages[st.page_ids[page_index]]
@@ -247,7 +251,8 @@ class PagedKVCache:
             self._restore(page, ls)
         page.last_access = self.clock
         page.dirty = True
-        self.kv = self.kv.at[:, page.offset].set(jnp.asarray(slab))
+        self.kv = self.kv.at[:, page.offset].set(
+            jax.device_put(slab, self.device))
 
     def read_page(self, seq_id: int, page_index: int) -> np.ndarray:
         """Byte-exact slab of one logical page, wherever it lives: resident

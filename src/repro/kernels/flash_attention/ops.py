@@ -1,7 +1,7 @@
 """Jit'd public wrapper for flash attention.
 
-Dispatches between the Pallas TPU kernel (on TPU, or interpret=True for CPU
-validation) and a chunked pure-XLA path (``lax.scan`` over kv blocks with
+Dispatches between the Pallas TPU kernel (compiled on TPU, interpreted on
+CPU) and a chunked pure-XLA path (``lax.scan`` over kv blocks with
 online softmax — same O(T) memory; used for dry-run lowering on CPU and as a
 portable fallback). Handles padding to block multiples and GQA.
 """
@@ -13,6 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .kernel import flash_attention_kernel
 from .ref import attention_ref
 
@@ -31,11 +32,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, window: Optional[int] = None,
                     scale: Optional[float] = None, q_offset: int = 0,
                     impl: str = "xla", block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True,
+                    block_k: int = 128,
                     p_bf16: bool = False) -> jnp.ndarray:
     """q [B,H,Tq,D], k/v [B,KH,Tk,D] -> [B,H,Tq,D].
 
-    impl: "kernel" (Pallas; interpret=True on CPU), "xla" (chunked scan,
+    impl: "kernel" (Pallas; interpreted on CPU), "xla" (chunked scan,
     lowers everywhere), "naive" (reference; O(T^2) memory — tests only).
     ``p_bf16``: cast softmax weights to bf16 for the PV matmul (halves the
     p-matrix HBM traffic; standard flash-kernel practice).
@@ -52,7 +53,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         out = flash_attention_kernel(
             qp, kp, vp, causal=causal, window=window, scale=scale,
             q_offset=q_offset, kv_len=Tk, block_q=bq, block_k=bk,
-            interpret=interpret)
+            interpret=interpret_mode())
         return out[:, :, :Tq]
     if impl == "xla":
         return _chunked_attention(q, k, v, causal, window, scale, q_offset,

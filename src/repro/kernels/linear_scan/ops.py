@@ -6,14 +6,14 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .kernel import diag_scan_kernel, gla_scan_kernel
 from .ref import diag_scan_ref, gla_scan_ref
 
 
 def diag_scan(a: jnp.ndarray, b: jnp.ndarray,
               h0: Optional[jnp.ndarray] = None, *, impl: str = "xla",
-              chunk: int = 256, interpret: bool = True
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+              chunk: int = 256) -> Tuple[jnp.ndarray, jnp.ndarray]:
     B, T, D = a.shape
     if h0 is None:
         h0 = jnp.zeros((B, D), a.dtype)
@@ -22,7 +22,8 @@ def diag_scan(a: jnp.ndarray, b: jnp.ndarray,
         if pad:
             a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
             b = jnp.pad(b, ((0, 0), (0, pad), (0, 0)))
-        h, hT = diag_scan_kernel(a, b, h0, chunk=chunk, interpret=interpret)
+        h, hT = diag_scan_kernel(a, b, h0, chunk=chunk,
+                                 interpret=interpret_mode())
         if pad:
             h = h[:, :T]
             hT = h[:, -1]
@@ -33,8 +34,8 @@ def diag_scan(a: jnp.ndarray, b: jnp.ndarray,
 
 
 def gla_scan(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, w: jnp.ndarray,
-             u: jnp.ndarray, *, impl: str = "xla", chunk: int = 64,
-             interpret: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+             u: jnp.ndarray, *, impl: str = "xla",
+             chunk: int = 64) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """RWKV6 wkv core. w = LOG decays. See ref.gla_scan_ref for shapes."""
     if impl == "kernel":
         B, T, Dk = r.shape
@@ -46,7 +47,8 @@ def gla_scan(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, w: jnp.ndarray,
             k = jnp.pad(k, widths)
             v = jnp.pad(v, widths)
             w = jnp.pad(w, widths)  # log-decay 0 = no decay; k=0 → no update
-        o, S = gla_scan_kernel(r, k, v, w, u, chunk=c, interpret=interpret)
+        o, S = gla_scan_kernel(r, k, v, w, u, chunk=c,
+                               interpret=interpret_mode())
         return (o[:, :T] if pad else o), S
     if impl == "xla":
         return gla_scan_ref(r, k, v, w, u)

@@ -8,6 +8,9 @@ carried across page steps; the block table is prefetched to SMEM so each
 page's DMA address is known before the step runs.
 
 TARGET: TPU (VMEM block = one KV page). Validated with interpret=True on CPU.
+Both matmuls run at ``Precision.HIGHEST``: at default precision the TPU
+may round f32 matmul inputs to bf16 (about 1e-3 relative error), and an f32
+KV pool is expected to give f32 attention.
 """
 from __future__ import annotations
 
@@ -20,6 +23,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _paged_kernel(bt_ref, len_ref, q_ref, kv_ref, o_ref, acc_ref, m_ref,
@@ -50,7 +54,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, kv_ref, o_ref, acc_ref, m_ref,
         vt = jnp.swapaxes(v, 0, 1)
         # s[kh, g, t] — batched over kv head
         s = jax.lax.dot_general(
-            qg, kt, (((2,), (2,)), ((0,), (0,))),
+            qg, kt, (((2,), (2,)), ((0,), (0,))), precision=HIGHEST,
             preferred_element_type=jnp.float32) * scale   # [KH, G, page]
         pos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (kv_heads, group, page_size), 2)
@@ -64,7 +68,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, kv_ref, o_ref, acc_ref, m_ref,
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + pexp.sum(-1, keepdims=True)
         acc_new = acc_prev * corr + jax.lax.dot_general(
-            pexp, vt, (((2,), (1,)), ((0,), (0,))),
+            pexp, vt, (((2,), (1,)), ((0,), (0,))), precision=HIGHEST,
             preferred_element_type=jnp.float32)           # [KH, G, D]
         m_ref[...] = m_new.reshape(H, 1)
         l_ref[...] = l_new.reshape(H, 1)

@@ -1,7 +1,11 @@
-"""Pure-jnp oracle for paged decode attention."""
+"""Pure-jnp oracle for paged decode attention (f32 matmuls at HIGHEST
+precision, so it stays an oracle on the TPU too)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def paged_attention_ref(q: jnp.ndarray, kv_pages: jnp.ndarray,
@@ -24,10 +28,11 @@ def paged_attention_ref(q: jnp.ndarray, kv_pages: jnp.ndarray,
     kk = jnp.repeat(k, group, axis=2)   # [B, T, H, D]
     vv = jnp.repeat(v, group, axis=2)
     s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
-                   kk.astype(jnp.float32)) * scale
+                   kk.astype(jnp.float32), precision=HIGHEST) * scale
     pos = jnp.arange(max_pages * page)[None, :]
     mask = pos < lengths[:, None]
     s = jnp.where(mask[:, None, :], s, -1e30)
     p = jnp.exp(s - s.max(-1, keepdims=True))
-    o = jnp.einsum("bht,bthd->bhd", p, vv.astype(jnp.float32))
+    o = jnp.einsum("bht,bthd->bhd", p, vv.astype(jnp.float32),
+                   precision=HIGHEST)
     return (o / jnp.maximum(p.sum(-1, keepdims=True), 1e-20)).astype(q.dtype)

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import interpret_mode
 from .kernel import combine_kernel, dispatch_kernel
 from .ref import combine_ref, dispatch_ref
 
@@ -51,22 +52,22 @@ def compute_slots(expert_id: jnp.ndarray, num_experts: int,
 
 
 def dispatch(x: jnp.ndarray, expert_id: jnp.ndarray, slot: jnp.ndarray,
-             num_experts: int, capacity: int, *, impl: str = "xla",
-             interpret: bool = True) -> jnp.ndarray:
+             num_experts: int, capacity: int, *,
+             impl: str = "xla") -> jnp.ndarray:
     if impl == "kernel":
         return dispatch_kernel(x, expert_id, slot, num_experts, capacity,
-                               interpret=interpret)
+                               interpret=interpret_mode())
     if impl == "xla":
         return dispatch_ref(x, expert_id, slot, num_experts, capacity)
     raise ValueError(impl)
 
 
 def combine(y: jnp.ndarray, expert_id: jnp.ndarray, slot: jnp.ndarray,
-            gates: jnp.ndarray, num_tokens: int, *, impl: str = "xla",
-            interpret: bool = True) -> jnp.ndarray:
+            gates: jnp.ndarray, num_tokens: int, *,
+            impl: str = "xla") -> jnp.ndarray:
     if impl == "kernel":
         return combine_kernel(y, expert_id, slot, gates, num_tokens,
-                              interpret=interpret)
+                              interpret=interpret_mode())
     if impl == "xla":
         return combine_ref(y, expert_id, slot, gates)
     raise ValueError(impl)

@@ -11,7 +11,7 @@ from typing import Dict, Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ArchConfig
 from ..sharding import DEFAULT_RULES, spec_for
@@ -20,12 +20,17 @@ from ..sharding import DEFAULT_RULES, spec_for
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """Arbitrary mesh (tests use small ones, elastic remesh uses survivors)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (tests use small ones, elastic remesh uses survivors).
+
+    Axes are Auto: the model code places tensors with
+    ``with_sharding_constraint``, which refuses Explicit axes (the default
+    of ``jax.make_mesh``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def axis_size(mesh: Mesh, name: str) -> int:

@@ -123,13 +123,12 @@ def moe_shardmap_apply(p, x, *, cfg: ArchConfig, mesh=None):
     hf = h.reshape(Ntok, d)
     eidf = eid.reshape(Ntok, K)
     gatesf = gates.reshape(Ntok, K)
-    from jax.experimental.shard_map import shard_map
-    y = shard_map(
+    y = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec,
                   P("model"), P("model"), P("model")),
         out_specs=tok_spec,
-        check_rep=False,
+        check_vma=False,
     )(hf, eidf, gatesf, p["w1"], p["w3"], p["w2"])
     y = y.reshape(B, T, d).astype(x.dtype)
 

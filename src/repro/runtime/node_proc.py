@@ -1080,6 +1080,11 @@ class ProcCluster:
         self._outbox_frames = self._inbox_frames
         self.rpc_chunk_bytes = int(rpc_chunk_bytes)
         self.rpc_timeout_s = float(rpc_timeout_s)
+        # Fork, even from a driver that already holds the TPU: a node process
+        # runs numpy host code only (the node server reaches no JAX call;
+        # the dispatch plan resolved below is numpy too), so it never
+        # initialises a JAX backend or touches the chip's runtime, and
+        # exits through os._exit without running the driver's teardown.
         self._ctx = mp.get_context("fork")  # spawn would re-import the world
         # Resolve the dispatch-plan kernel BEFORE the first fork: children
         # inherit the loaded module, instead of each paying the (possibly

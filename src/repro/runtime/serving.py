@@ -277,15 +277,17 @@ class TieredSlabStore(HostSlabStore):
 
 class KVShard:
     """One node's slice of the serving tier: a driver-side ``PagedKVCache``
-    modeling that node's HBM page pool, spilling through the tiered store."""
+    holding that node's HBM page pool on ``device``, spilling through the
+    tiered store."""
 
-    def __init__(self, tier: "ServingTier", node_id: int):
+    def __init__(self, tier: "ServingTier", node_id: int, device):
         self.node_id = node_id
         self.store = TieredSlabStore(tier, node_id)
         self.cache = PagedKVCache(
             num_layers=tier.num_layers, hbm_pages=tier.hbm_pages_per_node,
             page_size=tier.page_tokens, kv_heads=tier.kv_heads,
-            head_dim=tier.head_dim, dtype=tier.dtype, host_store=self.store)
+            head_dim=tier.head_dim, dtype=tier.dtype, host_store=self.store,
+            device=device)
 
 
 @dataclass
@@ -396,7 +398,11 @@ class ServingTier:
     def _shard(self, node_id: int) -> KVShard:
         shard = self._shards.get(node_id)
         if shard is None:
-            shard = self._shards[node_id] = KVShard(self, node_id)
+            import jax
+            # node i's pool lives on chip i; on one chip every pool shares it
+            devices = jax.devices()
+            shard = self._shards[node_id] = KVShard(
+                self, node_id, devices[node_id % len(devices)])
         return shard
 
     def _drop_shard(self, node_id: int) -> None:
@@ -678,18 +684,26 @@ class ServingTier:
                         for a, b in zip(got, want)))
 
     def attend(self, seq_ids: List[int], layer: int = 0,
-               impl: str = "xla") -> Dict[int, np.ndarray]:
+               impl: str = "kernel") -> Dict[int, np.ndarray]:
         """Run paged decode attention for a batch (grouped by shard — each
-        shard is one device pool).  The q vectors are deterministic too, so
-        outputs are comparable across backends."""
+        shard is one device pool, and its attention runs on that pool's
+        device).  The q vectors are deterministic too, so outputs are
+        comparable across backends.  A shard's part of the batch must fit
+        its HBM pool: restoring one sequence's pages must not evict another's
+        from the block tables already built."""
         from ..kernels.paged_attention.ops import paged_attention
-        import jax.numpy as jnp
+        import jax
         by_shard: Dict[int, List[int]] = {}
         for s in seq_ids:
             by_shard.setdefault(self._live_session(s).node, []).append(s)
         out: Dict[int, np.ndarray] = {}
         for node, seqs in by_shard.items():
             shard = self._shard(node)
+            if (sum(shard.cache.num_pages(s) for s in seqs)
+                    > self.hbm_pages_per_node):
+                raise ValueError(
+                    f"attention batch on node {node} holds more pages than "
+                    f"its HBM pool ({self.hbm_pages_per_node})")
             max_pages = max(shard.cache.num_pages(s) for s in seqs)
             tables = np.stack([shard.cache.block_table(s, max_pages)
                                for s in seqs])
@@ -698,9 +712,10 @@ class ServingTier:
             q = np.stack([np.full((self.kv_heads, self.head_dim),
                                   token_value(s, self.sessions[s].length),
                                   self.dtype) for s in seqs])
-            r = paged_attention(jnp.asarray(q), shard.cache.kv[layer],
-                                jnp.asarray(tables), jnp.asarray(lengths),
-                                impl=impl)
+            dev = shard.cache.device
+            r = paged_attention(jax.device_put(q, dev), shard.cache.kv[layer],
+                                jax.device_put(tables, dev),
+                                jax.device_put(lengths, dev), impl=impl)
             for i, s in enumerate(seqs):
                 out[s] = np.asarray(r[i])
         return out

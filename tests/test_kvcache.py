@@ -103,8 +103,7 @@ def _attend_both(kv, seqs, layer=0):
         (len(seqs), kv.kv_heads, kv.head_dim)).astype(np.float32)
     pages = kv.kv[layer]
     ref = paged_attention(q, pages, tables, lengths, impl="xla")
-    ker = paged_attention(q, pages, tables, lengths, impl="kernel",
-                          interpret=True)
+    ker = paged_attention(q, pages, tables, lengths, impl="kernel")
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     return np.asarray(ker)

@@ -244,6 +244,20 @@ def test_attend_runs_kernel_and_xla_identically_after_failover(tmp_path):
     _teardown(cluster, "inproc")
 
 
+def test_attend_refuses_a_batch_larger_than_its_pool(tmp_path):
+    """Restoring the pages of a batch that outgrows the HBM pool would
+    evict pages its block tables already name: attention over a stale slot
+    is refused, not computed."""
+    cluster = _cluster("inproc", tmp_path)
+    tier = _tier(cluster, hbm_pages_per_node=4)
+    tier.admit({1: 18})                   # 5 pages > 4 HBM slots
+    assert tier.verify(1)
+    with pytest.raises(ValueError, match="more pages than its HBM pool"):
+        tier.attend([1])
+    tier.close()
+    _teardown(cluster, "inproc")
+
+
 # -- property: random op interleavings vs unlimited-HBM reference (satellite) -
 _OPS = st.lists(
     st.tuples(st.integers(min_value=0, max_value=3),    # action
