@@ -2,8 +2,7 @@
 roofline summary. Prints ``name,us_per_call,derived`` CSV and writes the
 schema-versioned ``BENCH_cluster.json`` artifact (cluster shuffle placement,
 net bytes, recovery/degrade times) so the perf trajectory accumulates across
-PRs. The serving-tier rows land in their own ``BENCH_serving.json``
-(written by ``benchmarks/bench_serving.py``, schema v1).
+PRs.
 
 Usage::
 
@@ -36,8 +35,7 @@ def main(argv=None) -> None:
         os.environ["BENCH_SMOKE"] = "1"
 
     from repro.compile_cache import configure_compile_cache
-    from . import (bench_join, bench_procplane, bench_recovery,
-                   bench_serving, bench_shuffle)
+    from . import bench_join, bench_procplane, bench_recovery, bench_shuffle
     from .common import write_results_json
 
     configure_compile_cache()
@@ -56,7 +54,6 @@ def main(argv=None) -> None:
         bench_replicas.run()      # Fig. 4
         bench_recovery.run()      # Fig. 5 + elastic degrade
         bench_procplane.run()     # process data plane vs in-process
-        bench_serving.run()       # paged-KV serving tier -> BENCH_serving.json
         print("\n# roofline (per-device terms from the dry-run; see "
               "EXPERIMENTS.md)")
         roofline.run(write_csv=True)
@@ -67,7 +64,6 @@ def main(argv=None) -> None:
         bench_join.run()
         bench_recovery.run()
         bench_procplane.run()
-        bench_serving.run()
         roofline.run_fused()
     write_results_json(args.json_out, prefixes=CLUSTER_PREFIXES)
 

@@ -167,31 +167,42 @@ class PagedKVCache:
             self._offload(vp)
 
     def _offload(self, page: Page) -> None:
+        import jax
         assert page.offset is not None
-        # device -> host (CPU container: numpy copy of that page's slab)
-        slab = np.asarray(self.kv[:, page.offset])
-        self.host_store.put(page.page_id, slab)
+        with jax.profiler.TraceAnnotation("kvcache.offload",
+                                          page=page.page_id):
+            # device -> host (CPU container: numpy copy of that page's slab)
+            slab = np.asarray(self.kv[:, page.offset])
+            self.host_store.put(page.page_id, slab)
         self.stats["offloads"] += 1
         self.stats["offload_bytes"] += slab.nbytes
         self._free_slots.append(page.offset)
         page.offset = None
 
-    def _restore(self, page: Page, ls: LocalitySet) -> int:
+    def _restore(self, seq_id: int, page: Page, ls: LocalitySet) -> int:
         import jax
-        slot = self._alloc_slot(exclude_set=ls.name)
-        try:
-            slab = self.host_store.take(page.page_id)
-        except BaseException:
-            # a tiered store may fail mid-fetch (dead remote node); the slot
-            # must go back so the cache stays consistent for the retry
-            self._free_slots.append(slot)
-            raise
-        if slab is not None:
+        with jax.profiler.TraceAnnotation("kvcache.restore", seq=seq_id,
+                                          page=page.page_id):
+            slot = self._alloc_slot(exclude_set=ls.name)
+            try:
+                slab = self.host_store.take(page.page_id)
+            except BaseException:
+                # a tiered store may fail mid-fetch (dead remote node); the
+                # slot must go back so the cache stays consistent for the
+                # retry
+                self._free_slots.append(slot)
+                raise
+            if slab is not None:
+                self._write_slot(seq_id, slot, slab)
+                self.stats["fetches"] += 1
+            page.offset = slot
+        return slot
+
+    def _write_slot(self, seq_id: int, slot: int, slab: np.ndarray) -> None:
+        import jax
+        with jax.profiler.TraceAnnotation("kvcache.pool_write", seq=seq_id):
             self.kv = self.kv.at[:, slot].set(
                 jax.device_put(slab, self.device))
-            self.stats["fetches"] += 1
-        page.offset = slot
-        return slot
 
     def _alloc_slot(self, exclude_set: Optional[str] = None) -> int:
         while not self._free_slots:
@@ -230,7 +241,7 @@ class PagedKVCache:
         for i, pid in enumerate(st.page_ids[:max_pages]):
             page = self._pages[pid]
             if page.offset is None:
-                self._restore(page, ls)
+                self._restore(seq_id, page, ls)
             page.last_access = self.clock
             table[i] = page.offset
         return table
@@ -242,17 +253,15 @@ class PagedKVCache:
     def write_page(self, seq_id: int, page_index: int, slab: np.ndarray) -> None:
         """Overwrite one logical page's slab ([L, page, 2, KH, D]); restores
         the page to HBM first if it was offloaded."""
-        import jax
         st = self._seqs[seq_id]
         ls = self._sets[seq_id]
         page = self._pages[st.page_ids[page_index]]
         self.clock += 1
         if page.offset is None:
-            self._restore(page, ls)
+            self._restore(seq_id, page, ls)
         page.last_access = self.clock
         page.dirty = True
-        self.kv = self.kv.at[:, page.offset].set(
-            jax.device_put(slab, self.device))
+        self._write_slot(seq_id, page.offset, slab)
 
     def read_page(self, seq_id: int, page_index: int) -> np.ndarray:
         """Byte-exact slab of one logical page, wherever it lives: resident

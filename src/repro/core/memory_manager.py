@@ -247,9 +247,12 @@ class AdmissionController:
                     self._cv.notify_all()
                     self._fire("waiting")
                     try:
-                        granted = self._cv.wait_for(
-                            lambda: self._staging_headroom(nbytes),
-                            timeout=timeout)
+                        import jax
+                        with jax.profiler.TraceAnnotation(
+                                "memory.admission_wait"):
+                            granted = self._cv.wait_for(
+                                lambda: self._staging_headroom(nbytes),
+                                timeout=timeout)
                     finally:
                         self.waiting -= 1
                         self._cv.notify_all()

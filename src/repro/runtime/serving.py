@@ -520,17 +520,21 @@ class ServingTier:
         """Ship every current page slab of the sequence to its replica
         holder; on replica death, re-pick and retry once (degrading to
         no-replica only when no other node is alive)."""
+        import jax
         for _attempt in (0, 1):
             if sess.replica is None:
                 return
             try:
-                shard = self._shard(sess.node)
-                npages = shard.cache.num_pages(sess.seq_id)
-                for k in range(npages):
-                    slab = shard.cache.read_page(sess.seq_id, k)
-                    self.cluster.store_bytes(
-                        sess.replica, self._rep_name(sess.seq_id, k),
-                        slab.tobytes())
+                with jax.profiler.TraceAnnotation(
+                        "serving.replicate", node=sess.replica,
+                        seq=sess.seq_id):
+                    shard = self._shard(sess.node)
+                    npages = shard.cache.num_pages(sess.seq_id)
+                    for k in range(npages):
+                        slab = shard.cache.read_page(sess.seq_id, k)
+                        self.cluster.store_bytes(
+                            sess.replica, self._rep_name(sess.seq_id, k),
+                            slab.tobytes())
                 return
             except DeadNodeError:
                 sess.replica = self._replica_for(sess.node)
@@ -538,12 +542,15 @@ class ServingTier:
 
     def _sync_replica(self, sess: Session, page_index: int,
                       slab: np.ndarray) -> None:
+        import jax
         if sess.replica is None:
             return
         try:
-            self.cluster.store_bytes(
-                sess.replica, self._rep_name(sess.seq_id, page_index),
-                slab.tobytes())
+            with jax.profiler.TraceAnnotation(
+                    "serving.replicate", node=sess.replica, seq=sess.seq_id):
+                self.cluster.store_bytes(
+                    sess.replica, self._rep_name(sess.seq_id, page_index),
+                    slab.tobytes())
         except DeadNodeError:
             sess.replica = self._replica_for(sess.node)
             self._replicate_all(sess)
@@ -553,10 +560,12 @@ class ServingTier:
         """Run ``steps`` decode iterations over the batch (continuous
         batching: each sequence advances independently, surviving node
         deaths via replica failover).  Returns ``seq_id -> new length``."""
+        import jax
         out = {}
-        for _ in range(steps):
-            for seq_id in seq_ids:
-                out[seq_id] = self._decode_one(seq_id)
+        with jax.profiler.TraceAnnotation("serving.decode"):
+            for _ in range(steps):
+                for seq_id in seq_ids:
+                    out[seq_id] = self._decode_one(seq_id)
         return out
 
     def _decode_one(self, seq_id: int) -> int:
@@ -691,34 +700,43 @@ class ServingTier:
         comparable across backends.  A shard's part of the batch must fit
         its HBM pool: restoring one sequence's pages must not evict another's
         from the block tables already built."""
+        import jax
+        with jax.profiler.TraceAnnotation("serving.attend"):
+            by_shard: Dict[int, List[int]] = {}
+            for s in seq_ids:
+                by_shard.setdefault(self._live_session(s).node, []).append(s)
+            out: Dict[int, np.ndarray] = {}
+            for node, seqs in by_shard.items():
+                out.update(self._attend_shard(node, seqs, layer, impl))
+        return out
+
+    def _attend_shard(self, node: int, seqs: List[int], layer: int,
+                      impl: str) -> Dict[int, np.ndarray]:
         from ..kernels.paged_attention.ops import paged_attention
         import jax
-        by_shard: Dict[int, List[int]] = {}
-        for s in seq_ids:
-            by_shard.setdefault(self._live_session(s).node, []).append(s)
-        out: Dict[int, np.ndarray] = {}
-        for node, seqs in by_shard.items():
-            shard = self._shard(node)
-            if (sum(shard.cache.num_pages(s) for s in seqs)
-                    > self.hbm_pages_per_node):
-                raise ValueError(
-                    f"attention batch on node {node} holds more pages than "
-                    f"its HBM pool ({self.hbm_pages_per_node})")
-            max_pages = max(shard.cache.num_pages(s) for s in seqs)
-            tables = np.stack([shard.cache.block_table(s, max_pages)
-                               for s in seqs])
-            lengths = np.array([self.sessions[s].length for s in seqs],
-                               np.int32)
-            q = np.stack([np.full((self.kv_heads, self.head_dim),
-                                  token_value(s, self.sessions[s].length),
-                                  self.dtype) for s in seqs])
-            dev = shard.cache.device
+        shard = self._shard(node)
+        if (sum(shard.cache.num_pages(s) for s in seqs)
+                > self.hbm_pages_per_node):
+            raise ValueError(
+                f"attention batch on node {node} holds more pages than "
+                f"its HBM pool ({self.hbm_pages_per_node})")
+        max_pages = max(shard.cache.num_pages(s) for s in seqs)
+        tables = np.stack([shard.cache.block_table(s, max_pages)
+                           for s in seqs])
+        lengths = np.array([self.sessions[s].length for s in seqs], np.int32)
+        q = np.stack([np.full((self.kv_heads, self.head_dim),
+                              token_value(s, self.sessions[s].length),
+                              self.dtype) for s in seqs])
+        dev = shard.cache.device
+        # host time to enqueue the kernel: argument transfers, the layer's
+        # pool slice, and the call's trace, lowering and program fetch
+        with jax.profiler.TraceAnnotation("serving.dispatch", node=node):
             r = paged_attention(jax.device_put(q, dev), shard.cache.kv[layer],
                                 jax.device_put(tables, dev),
                                 jax.device_put(lengths, dev), impl=impl)
-            for i, s in enumerate(seqs):
-                out[s] = np.asarray(r[i])
-        return out
+        # the wait for the kernel and each session's row back to the host
+        with jax.profiler.TraceAnnotation("serving.fetch", node=node):
+            return {s: np.asarray(r[i]) for i, s in enumerate(seqs)}
 
     # -- lifecycle ------------------------------------------------------------
     def finish(self, seq_id: int) -> None:

@@ -6,6 +6,8 @@ Mosaic tiling refuses, VMEM overuse, unsupported dot precisions. The
 topology is described inside a fixture (never at import): only one process
 at a time may load the TPU library, and test workers import every file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -52,17 +54,56 @@ def no_persistent_cache():
         cc.reset_cache()
 
 
-@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
-def test_paged_attention_compiles_for_v5e(one_chip, no_persistent_cache,
-                                          geometry):
+def _shapes(one_chip, geometry):
+    """(q, pool, block tables, lengths) of one geometry on the chip."""
     heads, kv_heads, head_dim, dtype = GEOMETRIES[geometry]
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    args = (sds((BATCH, heads, head_dim), dtype),
+    return (sds((BATCH, heads, head_dim), dtype),
             sds((POOL_PAGES, PAGE_TOKENS, 2, kv_heads, head_dim), dtype),
             sds((BATCH, MAX_PAGES), jnp.int32),
             sds((BATCH,), jnp.int32))
+
+
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_paged_attention_compiles_for_v5e(one_chip, no_persistent_cache,
+                                          geometry):
+    args = _shapes(one_chip, geometry)
     compiled = jax.jit(paged_attention_kernel).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kernel_op_keeps_the_name_the_benchmark_finds(one_chip,
+                                                      no_persistent_cache,
+                                                      monkeypatch):
+    """The benchmark finds the kernel's device time by the op's name in the
+    trace, ``^%tpu_custom_call`` (``KERNELS`` in
+    ``bench/drivers/kv_serve.py``); a ``name=`` on the ``pallas_call``
+    renames the op and would leave ``paged_attn_roofline`` nothing to read.
+    The program calls the kernel eagerly, so what runs is the program of
+    the ``pallas_call`` alone: it is captured here and compiled."""
+    from repro.kernels.paged_attention import kernel
+    calls = []
+    pallas_call = kernel.pl.pallas_call
+
+    def capture(*args, **kwargs):
+        calls.append(pallas_call(*args, **kwargs))
+        return calls[-1]
+
+    monkeypatch.setattr(kernel.pl, "pallas_call", capture)
+    q, kv, tables, lengths = _shapes(one_chip, "minitron8b-bf16")
+    # a new function: a cached trace of the kernel would not call pallas_call
+    jax.eval_shape(lambda *a: paged_attention_kernel(*a), q, kv, tables,
+                   lengths)
+    hlo = calls[0].lower(tables, lengths, q, kv).compile().as_text()
+    names = [line.strip().removeprefix("ROOT ").split(" = ", 1)[0]
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert names
+    for name in names:
+        assert re.match(r"^%tpu_custom_call", name), (
+            f"the kernel's op is {name!r}: a benchmark PR must widen KERNELS "
+            f"in bench/drivers/kv_serve.py before the pallas_call gets a "
+            f"name=")
