@@ -1,13 +1,24 @@
-"""Jit'd wrapper for paged decode attention (kernel / xla fallback)."""
+"""Jit'd wrapper for paged decode attention (kernel / xla fallback).
+
+Each path is one ``jax.jit`` built at import, so its cache lives as long as
+the process: a call compiles once per (device, batch, max pages, dtype) and
+every later call of that shape dispatches the compiled program. An eager
+``pallas_call`` would trace, lower and fetch its program on every call.
+"""
 from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from .. import interpret_mode
 from .kernel import paged_attention_kernel
 from .ref import paged_attention_ref
+
+_kernel = jax.jit(paged_attention_kernel,
+                  static_argnames=("scale", "interpret"))
+_xla = jax.jit(paged_attention_ref, static_argnames=("scale",))
 
 
 def paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
@@ -20,9 +31,8 @@ def paged_attention(q: jnp.ndarray, kv_pages: jnp.ndarray,
     (the gather-based reference; lowers everywhere).
     """
     if impl == "kernel":
-        return paged_attention_kernel(q, kv_pages, block_tables, lengths,
-                                      scale=scale, interpret=interpret_mode())
+        return _kernel(q, kv_pages, block_tables, lengths, scale=scale,
+                       interpret=interpret_mode())
     if impl == "xla":
-        return paged_attention_ref(q, kv_pages, block_tables, lengths,
-                                   scale=scale)
+        return _xla(q, kv_pages, block_tables, lengths, scale=scale)
     raise ValueError(f"unknown impl {impl!r}")

@@ -697,9 +697,11 @@ class ServingTier:
         """Run paged decode attention for a batch (grouped by shard — each
         shard is one device pool, and its attention runs on that pool's
         device).  The q vectors are deterministic too, so outputs are
-        comparable across backends.  A shard's part of the batch must fit
-        its HBM pool: restoring one sequence's pages must not evict another's
-        from the block tables already built."""
+        comparable across backends.  Each kernel call's sessions must fit
+        their HBM pool together: restoring one sequence's pages must not
+        evict another's from the block tables already built.  A shard's part
+        of the batch that outgrows its pool runs in several calls, each of
+        sessions that fit; a session larger than the pool is refused."""
         import jax
         with jax.profiler.TraceAnnotation("serving.attend"):
             by_shard: Dict[int, List[int]] = {}
@@ -707,19 +709,34 @@ class ServingTier:
                 by_shard.setdefault(self._live_session(s).node, []).append(s)
             out: Dict[int, np.ndarray] = {}
             for node, seqs in by_shard.items():
-                out.update(self._attend_shard(node, seqs, layer, impl))
+                for part in self._pool_sized_parts(node, seqs):
+                    out.update(self._attend_shard(node, part, layer, impl))
         return out
+
+    def _pool_sized_parts(self, node: int,
+                          seqs: List[int]) -> List[List[int]]:
+        """``seqs`` in order, cut into runs whose pages fit the HBM pool."""
+        cache = self._shard(node).cache
+        parts: List[List[int]] = [[]]
+        used = 0
+        for s in seqs:
+            pages = cache.num_pages(s)
+            if pages > self.hbm_pages_per_node:
+                raise ValueError(
+                    f"session {s} on node {node} holds more pages than its "
+                    f"HBM pool ({self.hbm_pages_per_node})")
+            if used + pages > self.hbm_pages_per_node:
+                parts.append([])
+                used = 0
+            parts[-1].append(s)
+            used += pages
+        return parts
 
     def _attend_shard(self, node: int, seqs: List[int], layer: int,
                       impl: str) -> Dict[int, np.ndarray]:
         from ..kernels.paged_attention.ops import paged_attention
         import jax
         shard = self._shard(node)
-        if (sum(shard.cache.num_pages(s) for s in seqs)
-                > self.hbm_pages_per_node):
-            raise ValueError(
-                f"attention batch on node {node} holds more pages than "
-                f"its HBM pool ({self.hbm_pages_per_node})")
         max_pages = max(shard.cache.num_pages(s) for s in seqs)
         tables = np.stack([shard.cache.block_table(s, max_pages)
                            for s in seqs])
@@ -729,7 +746,8 @@ class ServingTier:
                               self.dtype) for s in seqs])
         dev = shard.cache.device
         # host time to enqueue the kernel: argument transfers, the layer's
-        # pool slice, and the call's trace, lowering and program fetch
+        # pool slice, and the call of its compiled program (compiled once
+        # per device, batch and max_pages)
         with jax.profiler.TraceAnnotation("serving.dispatch", node=node):
             r = paged_attention(jax.device_put(q, dev), shard.cache.kv[layer],
                                 jax.device_put(tables, dev),

@@ -36,3 +36,20 @@ def _pangea_isolation(request):
     yield
     if _sanitizer.enabled():
         _sanitizer.assert_clean(request.node.nodeid)
+
+
+@pytest.fixture
+def backend_compiles():
+    """A list that gains one entry for each backend compile request JAX
+    makes while the test runs (persistent-cache hits included): the event
+    that the benchmark counts as ``window_compiles``."""
+    import jax
+    seen = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    yield seen
+    jax.monitoring.unregister_event_duration_listener(listen)

@@ -99,6 +99,38 @@ def test_paged_kernel_sweep(case, dtype):
                                np.asarray(ref, np.float32), **_tol(dtype))
 
 
+def test_paged_kernel_compiles_once_per_shape(backend_compiles):
+    """The kernel path keeps one compiled program per shape for the whole
+    process: repeated calls of a shape compile nothing, and a new
+    ``max_pages`` compiles once. (A geometry no other test uses, so that
+    neither shape is compiled before this test.)"""
+    B, H, KH, D, P, page = 2, 6, 3, 16, 12, 4
+    q = _t((B, H, D), jnp.float32)
+    kv = _t((P, page, 2, KH, D), jnp.float32)
+    ln = jnp.asarray(np.array([2 * page + 1, 3 * page], np.int32))
+    # row b holds pages 5b, 5b + 1, ...: the live pages are the same at
+    # either width
+    tables = {mp: jnp.asarray(5 * np.arange(B, dtype=np.int32)[:, None]
+                              + np.arange(mp, dtype=np.int32))
+              for mp in (3, 5)}
+    outs = []
+    for call in range(3):
+        before = len(backend_compiles)
+        outs.append(paged_attention(q, kv, tables[3], ln, impl="kernel"))
+        outs[-1].block_until_ready()
+        if call:
+            assert len(backend_compiles) == before, f"call {call} compiled"
+    for out in outs[1:]:
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(outs[0]))
+    before = len(backend_compiles)
+    wide = paged_attention(q, kv, tables[5], ln, impl="kernel")
+    wide.block_until_ready()
+    assert len(backend_compiles) == before + 1
+    # pages past each length are masked: the wider table reads the same
+    np.testing.assert_allclose(np.asarray(wide), np.asarray(outs[0]),
+                               **_tol(jnp.float32))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("case", [(2, 64, 16, 16), (1, 100, 8, 32),
                                   (3, 32, 32, 32)])

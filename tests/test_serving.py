@@ -244,6 +244,30 @@ def test_attend_runs_kernel_and_xla_identically_after_failover(tmp_path):
     _teardown(cluster, "inproc")
 
 
+def test_attend_compiles_nothing_once_its_shapes_are_warm(
+        tmp_path, backend_compiles):
+    """A decode step whose batches keep their ``max_pages`` reuses the
+    programs of the step before: every layer's attention of the second
+    round runs without a compile request, and still equals the XLA path."""
+    cluster = _cluster("inproc", tmp_path)
+    tier = _tier(cluster)
+    tier.admit({1: 6, 2: 9})              # 2 and 3 pages of 4 tokens
+    for round_ in range(2):
+        if round_:
+            tier.decode([1, 2], steps=1)  # 7 and 10 tokens: same pages
+        before = len(backend_compiles)
+        ker = [tier.attend([1, 2], layer) for layer in range(tier.num_layers)]
+        if round_:
+            assert len(backend_compiles) == before
+    for layer in range(tier.num_layers):
+        xla = tier.attend([1, 2], layer, impl="xla")
+        for s in (1, 2):
+            np.testing.assert_allclose(xla[s], ker[layer][s], rtol=2e-5,
+                                       atol=2e-5)
+    tier.close()
+    _teardown(cluster, "inproc")
+
+
 def test_attend_refuses_a_batch_larger_than_its_pool(tmp_path):
     """Restoring the pages of a batch that outgrows the HBM pool would
     evict pages its block tables already name: attention over a stale slot
@@ -254,6 +278,24 @@ def test_attend_refuses_a_batch_larger_than_its_pool(tmp_path):
     assert tier.verify(1)
     with pytest.raises(ValueError, match="more pages than its HBM pool"):
         tier.attend([1])
+    tier.close()
+    _teardown(cluster, "inproc")
+
+
+def test_attend_splits_a_batch_that_outgrows_its_pool(tmp_path):
+    """Two sessions that each fit the HBM pool, but not together: their
+    attention runs in one call each and reads what each reads alone."""
+    cluster = _cluster("inproc", tmp_path)
+    tier = _tier(cluster, hbm_pages_per_node=4)
+    node = tier._affinity(1)
+    other = next(s for s in range(2, 1000) if tier._affinity(s) == node)
+    tier.admit({1: 10, other: 11})        # 3 + 3 pages > 4 HBM slots
+    assert {tier.sessions[s].node for s in (1, other)} == {node}
+    both = tier.attend([1, other])
+    for s in (1, other):
+        alone = tier.attend([s], impl="xla")[s]
+        np.testing.assert_allclose(both[s], alone, rtol=2e-5, atol=2e-5)
+        assert tier.verify(s)
     tier.close()
     _teardown(cluster, "inproc")
 

@@ -75,29 +75,33 @@ def test_paged_attention_compiles_for_v5e(one_chip, no_persistent_cache,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.fixture
+def short_locations():
+    """Source locations of one frame, as the benchmark runs (``bench/
+    harness.py``, ``configure_compilation_cache``). Inside a jit, a
+    ``pallas_call``'s op takes the name of its location when that holds
+    the call stack, and the name of its custom-call target when not."""
+    prev = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", prev)
+
+
 def test_kernel_op_keeps_the_name_the_benchmark_finds(one_chip,
                                                       no_persistent_cache,
-                                                      monkeypatch):
+                                                      short_locations):
     """The benchmark finds the kernel's device time by the op's name in the
     trace, ``^%tpu_custom_call`` (``KERNELS`` in
     ``bench/drivers/kv_serve.py``); a ``name=`` on the ``pallas_call``
     renames the op and would leave ``paged_attn_roofline`` nothing to read.
-    The program calls the kernel eagerly, so what runs is the program of
-    the ``pallas_call`` alone: it is captured here and compiled."""
-    from repro.kernels.paged_attention import kernel
-    calls = []
-    pallas_call = kernel.pl.pallas_call
-
-    def capture(*args, **kwargs):
-        calls.append(pallas_call(*args, **kwargs))
-        return calls[-1]
-
-    monkeypatch.setattr(kernel.pl, "pallas_call", capture)
+    What runs is the jitted program that ``ops.paged_attention`` calls: it
+    is compiled here."""
+    from repro.kernels.paged_attention import ops
     q, kv, tables, lengths = _shapes(one_chip, "minitron8b-bf16")
-    # a new function: a cached trace of the kernel would not call pallas_call
-    jax.eval_shape(lambda *a: paged_attention_kernel(*a), q, kv, tables,
-                   lengths)
-    hlo = calls[0].lower(tables, lengths, q, kv).compile().as_text()
+    hlo = ops._kernel.lower(q, kv, tables, lengths,
+                            interpret=False).compile().as_text()
     names = [line.strip().removeprefix("ROOT ").split(" = ", 1)[0]
              for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
