@@ -24,7 +24,7 @@ The device half (attention over the page pool) is ``kernels/paged_attention``.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -90,28 +90,37 @@ class SeqState:
 
 
 class PagedKVCache:
-    """Page-granular KV storage for one model (all layers share page geometry).
+    """Page-granular KV storage for the layers of one page layout.
 
-    Physical layout (device): ``kv[L, P, page_size, 2, kv_heads, head_dim]``
-    where P = hbm_pages. Logical pages beyond P live in the host store.
-    ``block_table(seq)`` yields physical slots for the attention kernel.
-    ``device`` is the chip that holds the pool (None: JAX's default device).
+    Physical layout (device): ``kv[L, P, page_size, *token_shape]`` where
+    P = hbm_pages and ``token_shape`` is what one token of one layer stores:
+    ``(2, kv_heads, head_dim)`` for a K and a V plane (the default, from
+    ``kv_heads`` and ``head_dim``), or ``(latent_dim,)`` for a latent cache
+    whose one vector per token every head reads (MLA). Slots, eviction,
+    offload, restore and the host store move a page's slab
+    ``[L, page_size, *token_shape]`` whole, whatever its shape. Logical
+    pages beyond P live in the host store. ``block_table(seq)`` yields
+    physical slots for the attention kernel. ``device`` is the chip that
+    holds the pool (None: JAX's default device).
     """
 
     def __init__(self, num_layers: int, hbm_pages: int, page_size: int,
-                 kv_heads: int, head_dim: int, dtype=np.float32,
-                 host_store: Optional[HostSlabStore] = None, device=None):
+                 kv_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None, dtype=np.float32,
+                 host_store: Optional[HostSlabStore] = None, device=None, *,
+                 token_shape: Optional[Tuple[int, ...]] = None):
         import jax.numpy as jnp  # local import: keep module importable w/o jax
         self.num_layers = num_layers
         self.hbm_pages = hbm_pages
         self.page_size = page_size
         self.kv_heads = kv_heads
         self.head_dim = head_dim
+        self.token_shape = (tuple(token_shape) if token_shape is not None
+                            else (2, kv_heads, head_dim))
         self.dtype = dtype
         self.device = device
-        self.kv = jnp.zeros(
-            (num_layers, hbm_pages, page_size, 2, kv_heads, head_dim),
-            dtype=dtype, device=device)
+        self.kv = jnp.zeros((num_layers, hbm_pages, page_size)
+                            + self.token_shape, dtype=dtype, device=device)
         self._free_slots: List[int] = list(range(hbm_pages))[::-1]
         self.paging = PagingSystem()
         self.clock = 1
@@ -126,8 +135,9 @@ class PagedKVCache:
     @property
     def slab_nbytes(self) -> int:
         """Bytes of one logical page's slab across all layers."""
-        return (self.num_layers * self.page_size * 2 * self.kv_heads
-                * self.head_dim * np.dtype(self.dtype).itemsize)
+        return (self.num_layers * self.page_size
+                * int(np.prod(self.token_shape))
+                * np.dtype(self.dtype).itemsize)
 
     # -- sequence lifecycle -----------------------------------------------------
     def start_sequence(self, seq_id: int) -> SeqState:
@@ -251,8 +261,8 @@ class PagedKVCache:
 
     # -- byte-exact page access ---------------------------------------------------
     def write_page(self, seq_id: int, page_index: int, slab: np.ndarray) -> None:
-        """Overwrite one logical page's slab ([L, page, 2, KH, D]); restores
-        the page to HBM first if it was offloaded."""
+        """Overwrite one logical page's slab ``[L, page, *token_shape]``;
+        restores the page to HBM first if it was offloaded."""
         st = self._seqs[seq_id]
         ls = self._sets[seq_id]
         page = self._pages[st.page_ids[page_index]]
@@ -273,8 +283,7 @@ class PagedKVCache:
             return np.asarray(self.kv[:, page.offset])
         slab = self.host_store.peek(page.page_id)
         if slab is None:   # offloaded before any write: an all-zero page
-            shape = (self.num_layers, self.page_size, 2,
-                     self.kv_heads, self.head_dim)
+            shape = (self.num_layers, self.page_size) + self.token_shape
             return np.zeros(shape, dtype=self.dtype)
         return np.asarray(slab)
 

@@ -36,3 +36,24 @@ def paged_attention_ref(q: jnp.ndarray, kv_pages: jnp.ndarray,
     o = jnp.einsum("bht,bthd->bhd", p, vv.astype(jnp.float32),
                    precision=HIGHEST)
     return (o / jnp.maximum(p.sum(-1, keepdims=True), 1e-20)).astype(q.dtype)
+
+
+def paged_latent_attention_ref(q: jnp.ndarray, kv_pages: jnp.ndarray,
+                               block_tables: jnp.ndarray,
+                               lengths: jnp.ndarray, *, value_dim: int,
+                               scale: float) -> jnp.ndarray:
+    """q: [B, H, C]; kv_pages: [P, page, C] (one latent vector a token);
+    block_tables: [B, max_pages]; lengths: [B]. Scores over all C channels,
+    values the leading ``value_dim``. Returns [B, H, value_dim]."""
+    B, H, C = q.shape
+    page = kv_pages.shape[1]
+    T = block_tables.shape[1] * page
+    kv = kv_pages[jnp.maximum(block_tables, 0)].reshape(B, T, C)
+    kv = kv.astype(jnp.float32)
+    s = jnp.einsum("bhc,btc->bht", q.astype(jnp.float32), kv,
+                   precision=HIGHEST) * scale
+    s = jnp.where((jnp.arange(T)[None, :] < lengths[:, None])[:, None, :],
+                  s, -1e30)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    o = jnp.einsum("bht,btc->bhc", p, kv[..., :value_dim], precision=HIGHEST)
+    return (o / jnp.maximum(p.sum(-1, keepdims=True), 1e-20)).astype(q.dtype)

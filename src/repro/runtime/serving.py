@@ -29,9 +29,38 @@ sequences whose KV caches contend for HBM.
   and resumes decode byte-identically; with no live replica it raises the
   same ``DeadNodeError("... must re-run")`` contract the shuffle honors.
 
-KV content is a deterministic function of ``(seq_id, position)``
-(``expected_page_slab``), so byte-identity across spill levels, backends,
-and failovers is checkable, not just plausible.
+A page's content, and each decode step's query, is a deterministic
+function of its indices, so byte-identity across spill levels, backends,
+and failovers is checkable, not just plausible. The function depends on the
+page layout:
+
+* **K/V planes** (``layout=None``, pages ``[L, page, 2, kv_heads,
+  head_dim]``, ``expected_page_slab``): every element of token ``t`` of
+  sequence ``s`` (all layers, heads, channels, K and V alike) holds
+  ``((s * 7919 + t * 104729) % 997) / 997``, and the query of a step at
+  ``n`` committed tokens holds the same function of ``(s, n)``
+  (``token_value``); one query head per KV head.
+* **Latent** (``layout=LatentLayout(...)``, pages ``[L, page, C]``,
+  ``expected_latent_slab``): MLA's absorbed decode caches one vector of
+  ``C = kv_lora_rank + qk_rope_head_dim`` channels a token and layer,
+  ``[c_kv ; k_rope]``, that all heads read. Element ``(l, t, c)`` of
+  sequence ``s`` holds ``latent_value(0, s, t, l, 0, c)``, and the query of
+  a step at ``n`` committed tokens, ``[q_heads, C]``, holds
+  ``latent_value(1, s, n, l, h, c)`` at layer ``l``, head ``h``, channel
+  ``c``, where, in uint32 arithmetic (every product and sum mod 2**32)::
+
+      h = 0x9E3779B1*stream + 0x85EBCA77*s + 0xC2B2AE3D*t
+          + 0x27D4EB2F*l + 0x165667B1*head + 0xD3A2646D*c
+      h ^= h >> 16; h *= 0x85EBCA6B; h ^= h >> 13; h *= 0xC2B2AE35
+      h ^= h >> 16
+      latent_value = (h >> 8) * 2**-23 - 1          # in [-1, 1), exact
+
+  then cast to the pool's dtype. Positions at or past the committed length
+  are zero. Every index moves every value, so a wrong layer, head, channel
+  slice or position changes what attention returns. The tier's latent
+  attention output is ``[q_heads, value_dim]``: the softmax over
+  ``scale * q . kv`` of the leading ``value_dim`` channels (``c_kv``);
+  applying ``W_UV`` is the model's.
 """
 from __future__ import annotations
 
@@ -51,6 +80,61 @@ def token_value(seq_id: int, t: int):
     """Deterministic KV fill for token ``t`` of a sequence — the serving
     tier's byte-identity oracle."""
     return ((seq_id * 7919 + t * 104729) % 997) / 997.0
+
+
+_LATENT_KEYS = (0x9E3779B1, 0x85EBCA77, 0xC2B2AE3D, 0x27D4EB2F, 0x165667B1,
+                0xD3A2646D)
+
+
+def latent_value(stream, seq_id, position, layer, head, channel
+                 ) -> np.ndarray:
+    """The latent contract's value at the given indices (broadcast
+    together), float32 in [-1, 1) (exact) before the cast to the pool's
+    dtype: stream 0 is the cache, stream 1 the query (module docstring)."""
+    with np.errstate(over="ignore"):       # uint32 arithmetic wraps
+        h = np.uint32(0)
+        for key, x in zip(_LATENT_KEYS,
+                          (stream, seq_id, position, layer, head, channel)):
+            h = h + np.asarray(x, np.uint32) * np.uint32(key)
+        h = h ^ (h >> np.uint32(16))
+        h = h * np.uint32(0x85EBCA6B)
+        h = h ^ (h >> np.uint32(13))
+        h = h * np.uint32(0xC2B2AE35)
+        h = h ^ (h >> np.uint32(16))
+    return (h >> np.uint32(8)).astype(np.float32) * np.float32(2.0 ** -23) \
+        - np.float32(1.0)
+
+
+def expected_latent_slab(seq_id: int, page_index: int, length: int, *,
+                         num_layers: int, page_tokens: int, latent_dim: int,
+                         dtype=np.float32) -> np.ndarray:
+    """Reference latent slab ``[L, page, C]`` for one logical page of a
+    sequence at ``length`` committed tokens (zeros past the length)."""
+    t = page_index * page_tokens + np.arange(page_tokens)
+    vals = latent_value(0, seq_id, t[None, :, None],
+                        np.arange(num_layers)[:, None, None], 0,
+                        np.arange(latent_dim)[None, None, :])
+    return np.where(t[None, :, None] < length, vals, 0.0).astype(dtype)
+
+
+def latent_query(seq_id: int, length: int, layer: int, q_heads: int,
+                 latent_dim: int, dtype=np.float32) -> np.ndarray:
+    """The query ``[q_heads, C]`` of a decode step at ``length`` committed
+    tokens, at one layer."""
+    return latent_value(1, seq_id, length, layer, np.arange(q_heads)[:, None],
+                        np.arange(latent_dim)[None, :]).astype(dtype)
+
+
+@dataclass(frozen=True)
+class LatentLayout:
+    """A latent page layout (MLA, absorbed decode): one vector of
+    ``latent_dim`` channels a token and layer, ``[c_kv ; k_rope]``, read by
+    ``q_heads`` query heads; the value is its leading ``value_dim``
+    channels, and scores are scaled by ``scale``."""
+    latent_dim: int
+    value_dim: int
+    q_heads: int
+    scale: float
 
 
 def expected_page_slab(seq_id: int, page_index: int, length: int, *,
@@ -285,9 +369,9 @@ class KVShard:
         self.store = TieredSlabStore(tier, node_id)
         self.cache = PagedKVCache(
             num_layers=tier.num_layers, hbm_pages=tier.hbm_pages_per_node,
-            page_size=tier.page_tokens, kv_heads=tier.kv_heads,
-            head_dim=tier.head_dim, dtype=tier.dtype, host_store=self.store,
-            device=device)
+            page_size=tier.page_tokens, dtype=tier.dtype,
+            host_store=self.store, device=device,
+            token_shape=tier.token_shape)
 
 
 @dataclass
@@ -301,10 +385,14 @@ class Session:
 
 class ServingTier:
     """The cluster-wide serving front end: admission, decode, spill,
-    replication, and failover for paged-KV sequences."""
+    replication, and failover for paged-KV sequences. ``layout`` picks the
+    page layout: None for K/V planes of ``kv_heads`` x ``head_dim``, a
+    ``LatentLayout`` for a latent cache (``kv_heads`` and ``head_dim`` then
+    go unused)."""
 
     def __init__(self, cluster, *, num_layers: int = 2, page_tokens: int = 4,
                  kv_heads: int = 2, head_dim: int = 4,
+                 layout: Optional[LatentLayout] = None,
                  hbm_pages_per_node: int = 16,
                  host_budget_bytes: Optional[int] = None,
                  dtype=np.float32, replicate: bool = True,
@@ -315,6 +403,7 @@ class ServingTier:
         self.page_tokens = page_tokens
         self.kv_heads = kv_heads
         self.head_dim = head_dim
+        self.layout = layout
         self.hbm_pages_per_node = hbm_pages_per_node
         self.host_budget_bytes = host_budget_bytes
         self.dtype = np.dtype(dtype)
@@ -326,13 +415,20 @@ class ServingTier:
         self._shards: Dict[int, KVShard] = {}
         self._hooks: Dict[str, Callable[[], None]] = {}
         self.stats = {"admitted": 0, "diverted": 0, "prefill_refusals": 0,
-                      "failovers": 0, "decode_steps": 0}
+                      "failovers": 0, "decode_steps": 0,
+                      "attention_calls": 0, "attention_tokens": 0}
 
     # -- geometry -------------------------------------------------------------
     @property
+    def token_shape(self) -> Tuple[int, ...]:
+        """What one token of one layer stores in a page."""
+        if self.layout is None:
+            return (2, self.kv_heads, self.head_dim)
+        return (self.layout.latent_dim,)
+
+    @property
     def slab_shape(self) -> Tuple[int, ...]:
-        return (self.num_layers, self.page_tokens, 2, self.kv_heads,
-                self.head_dim)
+        return (self.num_layers, self.page_tokens) + self.token_shape
 
     @property
     def slab_nbytes(self) -> int:
@@ -343,6 +439,11 @@ class ServingTier:
 
     def _expected_slab(self, seq_id: int, page_index: int,
                        length: int) -> np.ndarray:
+        if self.layout is not None:
+            return expected_latent_slab(
+                seq_id, page_index, length, num_layers=self.num_layers,
+                page_tokens=self.page_tokens,
+                latent_dim=self.layout.latent_dim, dtype=self.dtype)
         return expected_page_slab(
             seq_id, page_index, length, num_layers=self.num_layers,
             page_tokens=self.page_tokens, kv_heads=self.kv_heads,
@@ -732,26 +833,40 @@ class ServingTier:
             used += pages
         return parts
 
+    def _query(self, seq_id: int, layer: int) -> np.ndarray:
+        """The deterministic query of a session's next attention call."""
+        length = self.sessions[seq_id].length
+        if self.layout is not None:
+            return latent_query(seq_id, length, layer, self.layout.q_heads,
+                                self.layout.latent_dim, self.dtype)
+        return np.full((self.kv_heads, self.head_dim),
+                       token_value(seq_id, length), self.dtype)
+
     def _attend_shard(self, node: int, seqs: List[int], layer: int,
                       impl: str) -> Dict[int, np.ndarray]:
-        from ..kernels.paged_attention.ops import paged_attention
+        from ..kernels.paged_attention import ops
         import jax
         shard = self._shard(node)
         max_pages = max(shard.cache.num_pages(s) for s in seqs)
         tables = np.stack([shard.cache.block_table(s, max_pages)
                            for s in seqs])
         lengths = np.array([self.sessions[s].length for s in seqs], np.int32)
-        q = np.stack([np.full((self.kv_heads, self.head_dim),
-                              token_value(s, self.sessions[s].length),
-                              self.dtype) for s in seqs])
+        q = np.stack([self._query(s, layer) for s in seqs])
+        self.stats["attention_calls"] += 1
+        self.stats["attention_tokens"] += int(lengths.sum())
         dev = shard.cache.device
         # host time to enqueue the kernel: argument transfers, the layer's
         # pool slice, and the call of its compiled program (compiled once
         # per device, batch and max_pages)
         with jax.profiler.TraceAnnotation("serving.dispatch", node=node):
-            r = paged_attention(jax.device_put(q, dev), shard.cache.kv[layer],
-                                jax.device_put(tables, dev),
-                                jax.device_put(lengths, dev), impl=impl)
+            args = (jax.device_put(q, dev), shard.cache.kv[layer],
+                    jax.device_put(tables, dev), jax.device_put(lengths, dev))
+            if self.layout is None:
+                r = ops.paged_attention(*args, impl=impl)
+            else:
+                r = ops.paged_latent_attention(
+                    *args, value_dim=self.layout.value_dim,
+                    scale=self.layout.scale, impl=impl)
         # the wait for the kernel and each session's row back to the host
         with jax.profiler.TraceAnnotation("serving.fetch", node=node):
             return {s: np.asarray(r[i]) for i, s in enumerate(seqs)}

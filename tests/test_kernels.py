@@ -9,7 +9,8 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.linear_scan.ops import diag_scan, gla_scan
 from repro.kernels.linear_scan.ref import diag_scan_ref, gla_scan_ref
-from repro.kernels.paged_attention.ops import paged_attention
+from repro.kernels.paged_attention.ops import (paged_attention,
+                                               paged_latent_attention)
 from repro.kernels.paged_attention.ref import paged_attention_ref
 from repro.kernels.shuffle_dispatch.ops import combine, compute_slots, dispatch
 from repro.kernels.shuffle_dispatch.ref import combine_ref, dispatch_ref
@@ -129,6 +130,53 @@ def test_paged_kernel_compiles_once_per_shape(backend_compiles):
     # pages past each length are masked: the wider table reads the same
     np.testing.assert_allclose(np.asarray(wide), np.asarray(outs[0]),
                                **_tol(jnp.float32))
+
+
+LATENT_CASES = [
+    # B, H, latent (value + rope), value, pool pages, page, max pages
+    (3, 8, 40, 32, 24, 4, 6),
+    (2, 4, 40, 32, 16, 4, 5),
+    (1, 8, 24, 16, 8, 8, 3),
+]
+
+
+def _plain_latent_attention(q, kv, tables, lengths, value_dim, scale):
+    """Per sequence and head, in float64: softmax over scale * q . kv of the
+    leading ``value_dim`` channels, over the sequence's valid tokens."""
+    q, kv = np.asarray(q, np.float64), np.asarray(kv, np.float64)
+    out = []
+    for b, n in enumerate(np.asarray(lengths)):
+        toks = kv[np.asarray(tables)[b]].reshape(-1, kv.shape[-1])[:n]
+        s = scale * q[b] @ toks.T
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out.append(p @ toks[:, :value_dim] / p.sum(-1, keepdims=True))
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", LATENT_CASES)
+def test_paged_latent_kernel_sweep(case, dtype):
+    """The latent kernel (interpreted) and its XLA path against a plain
+    reference over ragged lengths: one token into a page, a page exactly
+    full, and tables padded past the live pages."""
+    B, H, C, V, P, page, maxp = case
+    q = _t((B, H, C), dtype)
+    kv = _t((P, page, C), dtype)
+    pages = RNG.permutation(P)[:B * maxp].reshape(B, maxp).astype(np.int32)
+    live = [1, maxp, max(1, maxp - 2)][:B]
+    lens = [n * page - cut for n, cut in zip(live, (page - 1, 0, 1))]
+    tables = np.where(np.arange(maxp)[None, :] < np.array(live)[:, None],
+                      pages, -1).astype(np.int32)
+    scale = C ** -0.5
+    want = _plain_latent_attention(q.astype(jnp.float32), kv.astype(
+        jnp.float32), np.maximum(tables, 0), lens, V, scale)
+    for impl in ("kernel", "xla"):
+        got = paged_latent_attention(q, kv, jnp.asarray(tables),
+                                     jnp.asarray(np.array(lens, np.int32)),
+                                     value_dim=V, scale=scale, impl=impl)
+        assert got.shape == (B, H, V) and got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   **_tol(dtype))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

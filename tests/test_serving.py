@@ -11,9 +11,18 @@ from hypothesis import strategies as st
 
 from repro.core import PagedKVCache
 from repro.runtime.cluster import Cluster, DeadNodeError
-from repro.runtime.serving import ServingTier, expected_page_slab
+from repro.runtime.serving import (LatentLayout, ServingTier,
+                                   expected_latent_slab, expected_page_slab,
+                                   latent_query)
 
 BACKENDS = ("inproc", "proc")
+# the two page layouts, at one slab size (2 layers x 4 tokens x 16 float32
+# = 512 bytes), so that the host budgets below spill alike: K/V planes of
+# 2 heads x 4 channels, or a latent vector of 12 + 4 channels read by 4
+# query heads
+LAYOUTS = {"gqa": None,
+           "latent": LatentLayout(latent_dim=16, value_dim=12, q_heads=4,
+                                  scale=0.25)}
 
 
 def _cluster(backend, tmp_path=None, **kw):
@@ -42,7 +51,8 @@ def _assert_clean(cluster):
         assert rep["reserved"] == 0, (nid, rep)
 
 
-def _tier(cluster, **kw):
+def _tier(cluster, layout="gqa", **kw):
+    kw["layout"] = LAYOUTS[layout]
     kw.setdefault("hbm_pages_per_node", 4)
     kw.setdefault("host_budget_bytes", 2048)
     return ServingTier(cluster, **kw)
@@ -87,12 +97,14 @@ def test_always_grant_baseline_never_diverts(tmp_path):
 
 
 # -- three-level spill (tentpole) ---------------------------------------------
-def test_three_level_spill_round_trips_byte_identically(tmp_path):
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_three_level_spill_round_trips_byte_identically(tmp_path, layout):
     """A sequence bigger than HBM with a tiny host budget pushes slabs
     through all three levels; reading the whole sequence back (block_table
-    restore) faults them home byte-identically."""
+    restore) faults them home byte-identically, in either page layout."""
     cluster = _cluster("inproc", tmp_path)
-    tier = _tier(cluster, hbm_pages_per_node=3, host_budget_bytes=1024)
+    tier = _tier(cluster, layout, hbm_pages_per_node=3,
+                 host_budget_bytes=1024)
     tier.admit({7: 20})           # 5 pages > 3 HBM slots
     tier.decode([7], steps=12)    # 32 tokens = 8 pages
     shard = tier._shards[tier.sessions[7].node]
@@ -124,16 +136,17 @@ def test_host_slabs_charge_the_nodes_memory_manager(tmp_path):
 PHASES = ("after_admit", "mid_decode", "during_restore", "during_spill")
 
 
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("phase", PHASES)
 def test_kill_at_phase_boundary_resumes_byte_identically(
-        tmp_path, backend, phase):
+        tmp_path, backend, phase, layout):
     """kill_node/SIGKILL at each serving phase boundary: the session must
     resume on its replica with byte-identical block-table contents, and no
-    reservation may leak on any surviving node."""
+    reservation may leak on any surviving node, in either page layout."""
     cluster = _cluster(backend, tmp_path)
     # budget 0 forces every eviction to level 3 so restore/spill phases fire
-    tier = _tier(cluster, hbm_pages_per_node=3,
+    tier = _tier(cluster, layout, hbm_pages_per_node=3,
                  host_budget_bytes=0 if phase in ("during_restore",
                                                   "during_spill") else 1024)
     seqs = {1: 10, 2: 6}
@@ -190,12 +203,13 @@ def test_sigkill_mid_decode_without_replica_demands_rerun(tmp_path, backend):
     _teardown(cluster, backend)
 
 
-def test_spill_target_death_mid_transfer_loses_nothing(tmp_path):
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_spill_target_death_mid_transfer_loses_nothing(tmp_path, layout):
     """Killing the level-3 spill *target* while a slab transfer is in
     flight must not lose the slab: the host copy is only dropped after the
     transfer confirms."""
     cluster = _cluster("inproc", tmp_path)
-    tier = _tier(cluster, hbm_pages_per_node=3, host_budget_bytes=0)
+    tier = _tier(cluster, layout, hbm_pages_per_node=3, host_budget_bytes=0)
     tier.admit({9: 10})
     node = tier.sessions[9].node
     target = tier._spill_target(node)
@@ -210,9 +224,11 @@ def test_spill_target_death_mid_transfer_loses_nothing(tmp_path):
     _teardown(cluster, "inproc")
 
 
-def test_replica_death_repicks_and_survives_primary_death_later(tmp_path):
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_replica_death_repicks_and_survives_primary_death_later(tmp_path,
+                                                               layout):
     cluster = _cluster("inproc", tmp_path)
-    tier = _tier(cluster)
+    tier = _tier(cluster, layout)
     tier.admit({4: 8})
     tier.decode([4], steps=2)
     sess = tier.sessions[4]
@@ -229,9 +245,11 @@ def test_replica_death_repicks_and_survives_primary_death_later(tmp_path):
 
 
 # -- attention over the serving pool ------------------------------------------
-def test_attend_runs_kernel_and_xla_identically_after_failover(tmp_path):
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_attend_runs_kernel_and_xla_identically_after_failover(tmp_path,
+                                                              layout):
     cluster = _cluster("inproc", tmp_path)
-    tier = _tier(cluster)
+    tier = _tier(cluster, layout)
     tier.admit({1: 6, 2: 9})
     tier.decode([1, 2], steps=3)
     cluster.kill_node(tier.sessions[1].node)
@@ -332,13 +350,19 @@ def test_random_interleavings_match_unlimited_hbm_reference(ops):
     """Any interleaving of admit/decode/read/finish over the spilling tier
     (3 HBM slots, 512-byte host budget => all three spill levels exercised)
     stays byte-identical to a reference PagedKVCache with unlimited HBM that
-    never evicts, spills, or restores."""
+    never evicts, spills, or restores, in either page layout."""
+    for layout in sorted(LAYOUTS):
+        _interleave_against_reference(ops, LAYOUTS[layout])
+
+
+def _interleave_against_reference(ops, layout):
     cluster = Cluster(3, node_capacity=8 << 20, page_size=1 << 14,
                       replication_factor=1, admission=True)
-    tier = ServingTier(cluster, hbm_pages_per_node=3, host_budget_bytes=512)
+    tier = ServingTier(cluster, hbm_pages_per_node=3, host_budget_bytes=512,
+                       layout=layout)
     ref = PagedKVCache(num_layers=tier.num_layers, hbm_pages=512,
-                       page_size=tier.page_tokens, kv_heads=tier.kv_heads,
-                       head_dim=tier.head_dim)
+                       page_size=tier.page_tokens,
+                       token_shape=tier.token_shape)
     try:
         lengths = {}
         for action, slot, n in ops:
@@ -377,3 +401,71 @@ def test_expected_page_slab_is_deterministic_and_masked():
     assert a.tobytes() == b.tobytes()
     assert (a[:, 2:] == 0).all()      # positions 6,7 past the length
     assert (a[:, :2] != 0).all()
+
+
+def test_expected_latent_slab_varies_with_every_index_and_is_masked():
+    a = expected_latent_slab(3, 1, 6, num_layers=2, page_tokens=4,
+                             latent_dim=10)
+    assert a.tobytes() == expected_latent_slab(
+        3, 1, 6, num_layers=2, page_tokens=4, latent_dim=10).tobytes()
+    assert a.shape == (2, 4, 10)
+    assert (a[:, 2:] == 0).all()      # positions 6,7 past the length
+    live = a[:, :2]
+    assert (live != 0).all() and (np.abs(live) < 1).all()
+    # distinct over layers, tokens and channels, and between sequences
+    assert len(np.unique(live)) == live.size
+    other = expected_latent_slab(4, 1, 6, num_layers=2, page_tokens=4,
+                                 latent_dim=10)
+    assert not np.isin(live, other[:, :2]).any()
+    q = latent_query(3, 6, 0, 4, 10)
+    assert q.shape == (4, 10) and len(np.unique(q)) == q.size
+    assert not np.array_equal(q, latent_query(3, 6, 1, 4, 10))
+    assert not np.array_equal(q, latent_query(3, 7, 0, 4, 10))
+
+
+def test_latent_attention_is_published_mla(tmp_path, monkeypatch):
+    """The tier's latent output is MLA's attention (DeepSeek-V2/V3): with
+    ``W_UK`` absorbed into each head's query and ``W_UV`` applied to what
+    the tier returns, it equals per-head attention over expanded keys
+    ``K_i = [W_UK_i c ; k_rope]`` and values ``V_i = W_UV_i c``, where
+    ``[c ; k_rope]`` is each token's cached vector (seeded random weights,
+    float32 at the highest precision, 8 heads, 32 + 8 latent channels,
+    4-token pages)."""
+    heads, lora, rope, nope, vdim, scale = 8, 32, 8, 16, 16, 0.2
+    rng = np.random.default_rng(7)
+    w_uk = rng.normal(size=(lora, heads, nope)) / np.sqrt(lora)
+    w_uv = rng.normal(size=(lora, heads, vdim)) / np.sqrt(lora)
+    q_nope = rng.normal(size=(heads, nope))
+    q_rope = rng.normal(size=(heads, rope))
+    # absorbed decode: each head's query against the cached vector is
+    # [W_UK_i^T q_nope_i ; q_rope_i]
+    q_lat = np.concatenate(
+        [np.einsum("lhn,hn->hl", w_uk, q_nope), q_rope], -1)
+    monkeypatch.setattr(ServingTier, "_query", lambda self, s, layer:
+                        q_lat.astype(self.dtype))
+    cluster = _cluster("inproc", tmp_path)
+    tier = ServingTier(cluster, num_layers=2, page_tokens=4,
+                       layout=LatentLayout(lora + rope, lora, heads, scale),
+                       hbm_pages_per_node=16)
+    try:
+        tier.admit({5: 13})
+        tier.decode([5], steps=2)
+        n = tier.sessions[5].length
+        for layer in range(2):
+            o_lat = tier.attend([5], layer)[5]                 # [H, lora]
+            got = np.einsum("hl,lhv->hv", o_lat.astype(np.float64), w_uv)
+            pages = np.concatenate(tier.sequence_slabs(5), axis=1)[layer]
+            cache = pages[:n].astype(np.float64)               # [T, C]
+            c, k_rope = cache[:, :lora], cache[:, lora:]
+            k = np.concatenate(
+                [np.einsum("tl,lhn->thn", c, w_uk),
+                 np.broadcast_to(k_rope[:, None], (n, heads, rope))], -1)
+            v = np.einsum("tl,lhv->thv", c, w_uv)
+            q = np.concatenate([q_nope, q_rope], -1)
+            s = scale * np.einsum("hd,thd->ht", q, k)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            want = np.einsum("ht,thv->hv", p / p.sum(-1, keepdims=True), v)
+            np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    finally:
+        tier.close()
+    _teardown(cluster, "inproc")

@@ -111,3 +111,26 @@ def test_kernel_op_keeps_the_name_the_benchmark_finds(one_chip,
             f"the kernel's op is {name!r}: a benchmark PR must widen KERNELS "
             f"in bench/drivers/kv_serve.py before the pallas_call gets a "
             f"name=")
+
+
+def test_latent_kernel_compiles_for_v5e_at_deepseek_v3_widths(
+        one_chip, no_persistent_cache, short_locations):
+    """The latent (MLA) kernel that ``ops.paged_latent_attention`` calls, at
+    DeepSeek-V3's widths: 128 query heads over 576-channel latent pages of
+    64 tokens in bfloat16, 512-channel values, a 1,024-page pool and a
+    batch of two 39k-token sessions. Its op keeps the name that ``KERNELS``
+    of ``bench/drivers/kv_serve_mla.py`` finds."""
+    from repro.kernels.paged_attention import ops
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    hlo = ops._latent_kernel.lower(
+        sds((2, 128, 576), jnp.bfloat16), sds((1024, 64, 576), jnp.bfloat16),
+        sds((2, 610), jnp.int32), sds((2,), jnp.int32), value_dim=512,
+        scale=0.1352337788608801, interpret=False).compile().as_text()
+    names = [line.strip().removeprefix("ROOT ").split(" = ", 1)[0]
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert names
+    assert all(re.match(r"^%tpu_custom_call", name) for name in names), names
