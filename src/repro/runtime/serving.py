@@ -867,9 +867,11 @@ class ServingTier:
                 r = ops.paged_latent_attention(
                     *args, value_dim=self.layout.value_dim,
                     scale=self.layout.scale, impl=impl)
-        # the wait for the kernel and each session's row back to the host
+        # the wait for the kernel and one copy of the call's whole output to
+        # the host, split there into each session's row
         with jax.profiler.TraceAnnotation("serving.fetch", node=node):
-            return {s: np.asarray(r[i]) for i, s in enumerate(seqs)}
+            rows = np.asarray(r)
+            return {s: rows[i] for i, s in enumerate(seqs)}
 
     # -- lifecycle ------------------------------------------------------------
     def finish(self, seq_id: int) -> None:

@@ -318,6 +318,108 @@ def test_attend_splits_a_batch_that_outgrows_its_pool(tmp_path):
     _teardown(cluster, "inproc")
 
 
+def _same_node_sessions(tier, n):
+    """``n`` session ids whose affinity is one node."""
+    node = tier._affinity(1)
+    return [s for s in range(1, 1000) if tier._affinity(s) == node][:n]
+
+
+class _CountingOutput:
+    """A kernel call's output that counts how the tier reads it back:
+    ``items`` device rows indexed, ``copies`` whole-array host copies."""
+
+    def __init__(self, array):
+        self.array = array
+        self.items = 0
+        self.copies = 0
+
+    def __getitem__(self, i):
+        self.items += 1
+        return self.array[i]
+
+    def __array__(self, dtype=None, copy=None):
+        self.copies += 1
+        return np.asarray(self.array, dtype)
+
+
+def _count_kernel_outputs(monkeypatch, layout):
+    """Wrap the tier's kernel entry point so that each call returns a
+    ``_CountingOutput``; returns the list of them, one a call."""
+    from repro.kernels.paged_attention import ops
+    name = "paged_attention" if layout == "gqa" else "paged_latent_attention"
+    real = getattr(ops, name)
+    calls = []
+
+    def counted(*args, **kw):
+        calls.append(_CountingOutput(real(*args, **kw)))
+        return calls[-1]
+
+    monkeypatch.setattr(ops, name, counted)
+    return calls
+
+
+@pytest.mark.parametrize("batch", [1, 4])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_attend_reads_each_call_back_in_one_host_copy(tmp_path, monkeypatch,
+                                                      layout, batch):
+    """One kernel call's output comes back to the host whole, once, and no
+    device row of it is indexed; each session's result is row ``i`` of
+    that output, its dtype, shape and bytes unchanged."""
+    cluster = _cluster("inproc", tmp_path)
+    tier = _tier(cluster, layout, hbm_pages_per_node=8)
+    try:
+        seqs = _same_node_sessions(tier, batch)
+        tier.admit({s: 5 + k for k, s in enumerate(seqs)})  # 2 pages each
+        calls = _count_kernel_outputs(monkeypatch, layout)
+        out = tier.attend(seqs)
+        assert len(calls) == 1
+        assert (calls[0].copies, calls[0].items) == (1, 0)
+        rows = np.asarray(calls[0].array)
+        want_shape = ((tier.kv_heads, tier.head_dim) if layout == "gqa" else
+                      (tier.layout.q_heads, tier.layout.value_dim))
+        assert sorted(out) == sorted(seqs)
+        for i, s in enumerate(seqs):
+            assert out[s].dtype == rows.dtype == tier.dtype
+            assert out[s].shape == want_shape
+            assert out[s].tobytes() == rows[i].tobytes()
+    finally:
+        tier.close()
+        _teardown(cluster, "inproc")
+
+
+@pytest.mark.parametrize("split", [False, True],
+                         ids=["one_call", "pool_sized_parts"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_attend_gives_each_session_its_own_row(tmp_path, monkeypatch, layout,
+                                               split):
+    """Several sessions in one kernel call, or cut into several calls by
+    their HBM pool: each result is the session's own attention, what it
+    reads alone, and no two sessions' results are alike."""
+    cluster = _cluster("inproc", tmp_path)
+    tier = _tier(cluster, layout, hbm_pages_per_node=4)
+    try:
+        if split:                          # 3 + 3 pages > 4 HBM slots
+            seqs = _same_node_sessions(tier, 2)
+            sizes = {seqs[0]: 10, seqs[1]: 11}
+        else:                              # 1 page each, 4 in the pool
+            seqs = _same_node_sessions(tier, 4)
+            sizes = {s: 1 + k for k, s in enumerate(seqs)}
+        tier.admit(sizes)
+        assert len({tier.sessions[s].node for s in seqs}) == 1
+        calls = _count_kernel_outputs(monkeypatch, layout)
+        got = tier.attend(seqs)
+        assert len(calls) == (2 if split else 1)
+        monkeypatch.undo()
+        for s in seqs:
+            alone = tier.attend([s], impl="xla")[s]
+            np.testing.assert_allclose(got[s], alone, rtol=2e-5, atol=2e-5)
+        for a, b in zip(seqs, seqs[1:]):
+            assert not np.allclose(got[a], got[b])
+    finally:
+        tier.close()
+        _teardown(cluster, "inproc")
+
+
 # -- property: random op interleavings vs unlimited-HBM reference (satellite) -
 _OPS = st.lists(
     st.tuples(st.integers(min_value=0, max_value=3),    # action
