@@ -5,7 +5,7 @@
 Input points are a write-through locality set; the derived points-with-norms
 are a write-back set (exactly the paper's setup). Each iteration scans the
 sets through the buffer pool with the data-aware paging policy; compute is
-jitted JAX. Compare with the layered baseline in benchmarks/bench_kmeans.py.
+jitted JAX.
 """
 import argparse
 import time

@@ -22,11 +22,11 @@ Because capacity (and so every region offset) is a pure function of
 the spill store and the durable page log persist them as the same opaque page
 images as row pages (layout-oblivious durability).
 
-The fused hot-path kernel lives here too: :func:`fused_partition_crc` does
-reducer-hash -> dispatch plan -> per-column gather -> per-partition
-incremental CRC32 in one vectorized pass per block (the host analogue of
-``kernels/shuffle_dispatch``; its ``ops`` module re-exports this so the
-kernel package stays the single import point for dispatch math).
+:func:`fused_partition_crc` does reducer-hash -> dispatch plan -> per-column
+gather -> per-partition incremental CRC32 in one vectorized pass per block,
+materializing the routed block. No production path calls it: the columnar
+map lands rows straight into their pages (``ColumnarShuffleService.
+add_gathered``), and the tests hold that landing to this reference.
 
 Checksum compatibility: :func:`columnar_content_checksum` computes the exact
 ``replication.record_content_checksum`` value from column arrays without
@@ -504,8 +504,8 @@ def fused_partition_crc(keys: np.ndarray, columns: Dict[str, np.ndarray],
                         crcs: Optional[List[int]] = None):
     """One fused pass over a column block: reducer hash -> dispatch plan
     (stable argsort over narrow partition ids + bincount, the
-    ``host_dispatch_plan`` contract) -> per-column contiguous gather ->
-    per-partition CRC32 chained into ``crcs``.
+    ``runtime.cluster.dispatch_plan`` contract) -> per-column contiguous
+    gather -> per-partition CRC32 chained into ``crcs``.
 
     Returns ``(routed, counts, offsets, crcs)`` where ``routed`` holds each
     column re-ordered so partition ``r`` occupies rows

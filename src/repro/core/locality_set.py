@@ -56,7 +56,7 @@ class LocalitySet:
         self._next_local_id = 0
         # paging-system hook; set by BufferPool.create_set
         self._on_attr_update = None
-        # per-set counters for the benchmarks (paper reports page-out volume)
+        # per-set counters (the paper reports page-out volume)
         self.stats = {"evictions": 0, "spill_bytes": 0, "fetch_bytes": 0}
 
     # -- attribute transitions (these drive the §6 priority model) ------------

@@ -51,7 +51,7 @@ def derive_staging_cap(capacity: int, watermark: float) -> int:
 
 class SpillStore:
     """Secondary storage for evicted pages. In-memory by default; set
-    ``directory`` to spill to real files (used by the I/O benchmarks).
+    ``directory`` to spill to real files (a cluster's ``spill_dir``).
     Tracks every page id it holds so ``clear()`` can delete them all when the
     owning node goes away (PR-3 leak fix: spill files used to outlive their
     pool)."""

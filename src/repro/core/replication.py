@@ -13,11 +13,10 @@ source and target partitionings is a *conflicting object* — if that node dies,
 neither copy survives. Conflicting objects are identified at partition time
 and replicated separately to other nodes. For a random partitioning the
 expected conflicting count is ``N/K`` (N objects, K nodes) — asserted by a
-property test and reported by ``benchmarks/bench_recovery.py``.
+property test.
 
-This module operates on numpy record arrays per node. It is used three ways:
-dataset shards (data pipeline), checkpoint tensor shards (checkpoint/), and
-the paper-fidelity benchmarks.
+This module operates on numpy record arrays per node. It is used two ways:
+dataset shards (data pipeline) and checkpoint tensor shards (checkpoint/).
 """
 from __future__ import annotations
 

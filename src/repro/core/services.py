@@ -515,7 +515,7 @@ class ColumnarShuffleService:
         from the source block straight into its pre-provisioned pages —
         ``np.take(..., out=page_region)``, no routed intermediate — while
         chaining the per-field partition CRCs over the landed bytes.
-        ``order``/``offsets`` are a ``host_dispatch_plan`` result over this
+        ``order``/``offsets`` are a ``dispatch_plan`` result over this
         block's reducer ids."""
         itemsize = self.dtype.itemsize
         bounds = (offsets.tolist() if hasattr(offsets, "tolist")

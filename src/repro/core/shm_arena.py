@@ -95,7 +95,7 @@ class ShmArena:
         # transfer engine ships shards in parallel)
         self._alloc_lock = tracked_lock("shm_arena")
         # Observability: the leak check wants in-use == 0 at close, the
-        # benchmark wants peak occupancy.
+        # sanitizer tests read peak occupancy.
         self.frames_in_use = 0
         self.peak_frames = 0
         self.puts = 0

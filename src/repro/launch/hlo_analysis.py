@@ -1,4 +1,4 @@
-"""Post-SPMD HLO analysis for the roofline (launch/dryrun + benchmarks).
+"""Post-SPMD HLO analysis for the roofline.
 
 ``compiled.cost_analysis()`` does NOT scale ``while`` bodies by trip count
 (verified empirically: a 10-iteration scan of a matmul reports the FLOPs of

@@ -56,8 +56,7 @@ from ..core.attributes import AttributeSet, StorageScheme
 from ..core.buffer_pool import BufferPool, SpillStore
 from ..core.columnar import (ColumnarWriter, ColumnLayout, _field_layout,
                              columns_crc32, columns_to_records,
-                             fused_partition_crc, iter_column_blocks,
-                             read_all_columnar, read_block,
+                             iter_column_blocks, read_all_columnar, read_block,
                              records_to_columns, route_partition_ids,
                              segment_sum)
 from ..core.locality_set import LocalitySet
@@ -81,80 +80,22 @@ from .transfer import TransferEngine, copy_set
 from .watchdog import StepTimer
 
 
-def _host_dispatch_plan(partition_ids: np.ndarray, num_partitions: int):
-    """Host-side analogue of ``kernels/shuffle_dispatch``'s slot assignment;
-    the device kernel version is preferred when importable."""
+# Threads in each cluster's transfer engine (both backends).
+TRANSFER_WORKERS = 4
+
+
+def dispatch_plan(partition_ids: np.ndarray, num_partitions: int):
+    """Group a batch by destination partition in one stable pass. Returns
+    ``(order, counts, offsets)``: ``counts`` has ``num_partitions`` entries
+    and ``order[offsets[p]:offsets[p+1]]`` are partition ``p``'s rows, in
+    their input order."""
+    partition_ids = np.asarray(partition_ids)
     order = np.argsort(partition_ids, kind="stable")
     counts = np.bincount(partition_ids, minlength=num_partitions)
     offsets = np.empty(len(counts) + 1, np.int64)
     offsets[0] = 0
     np.cumsum(counts, out=offsets[1:])
     return order, counts, offsets
-
-
-_dispatch_plan_impl = None
-_dispatch_impl_name = "unresolved"
-
-
-def _resolve_dispatch_plan():
-    """Resolve the dispatch-plan implementation exactly once. A failed import
-    is not cached by Python, so retrying per batch would re-run the whole
-    failing jax import on every call — the PR-7 bugfix also records *which*
-    implementation won, so benchmarks can report it instead of the resolution
-    being silently swallowed."""
-    global _dispatch_plan_impl, _dispatch_impl_name
-    if _dispatch_plan_impl is None:
-        try:
-            from ..kernels.shuffle_dispatch.ops import host_dispatch_plan
-            _dispatch_plan_impl = host_dispatch_plan
-            _dispatch_impl_name = "kernels.shuffle_dispatch"
-        except ImportError:  # kernels need jax; the cluster runtime must not
-            _dispatch_plan_impl = _host_dispatch_plan
-            _dispatch_impl_name = "host-fallback"
-    return _dispatch_plan_impl
-
-
-def dispatch_impl() -> str:
-    """Which dispatch-plan implementation is active:
-    ``"kernels.shuffle_dispatch"`` (the kernel package imported cleanly) or
-    ``"host-fallback"`` (this module's numpy copy). Resolves on first call."""
-    _resolve_dispatch_plan()
-    return _dispatch_impl_name
-
-
-def dispatch_plan(partition_ids: np.ndarray, num_partitions: int):
-    """Group a batch by destination partition in one stable pass. Mirrors the
-    MoE shuffle-dispatch slot assignment (``kernels/shuffle_dispatch``), whose
-    host-side helper is used when available; records land contiguously per
-    partition: ``order[offsets[p]:offsets[p+1]]`` are partition ``p``'s rows."""
-    return _resolve_dispatch_plan()(partition_ids, num_partitions)
-
-
-_partition_crc_impl = None
-_partition_crc_name = "unresolved"
-
-
-def _resolve_partition_crc():
-    """Same once-only resolution for the fused hash-partition + CRC pass:
-    prefer the kernel package's export, fall back to the numpy implementation
-    in ``core.columnar`` (they are the same host pass — the fallback exists so
-    the cluster runtime never needs the kernels package's jax import)."""
-    global _partition_crc_impl, _partition_crc_name
-    if _partition_crc_impl is None:
-        try:
-            from ..kernels.shuffle_dispatch.ops import host_partition_crc
-            _partition_crc_impl = host_partition_crc
-            _partition_crc_name = "kernels.shuffle_dispatch"
-        except ImportError:
-            _partition_crc_impl = fused_partition_crc
-            _partition_crc_name = "core.columnar"
-    return _partition_crc_impl
-
-
-def partition_crc_impl() -> str:
-    """Which fused partition+CRC implementation is active (for benchmarks)."""
-    _resolve_partition_crc()
-    return _partition_crc_name
 
 
 class DeadNodeError(RuntimeError):
@@ -427,7 +368,7 @@ class Cluster:
     def __init__(self, num_nodes: int, node_capacity: int = 32 << 20,
                  page_size: int = 1 << 18, replication_factor: int = 1,
                  spill_dir: Optional[str] = None,
-                 transfer_workers: int = 4, policy: str = "data-aware",
+                 policy: str = "data-aware",
                  admission: bool = True,
                  admission_deadline_s: float = 0.05,
                  admission_timeout_s: float = 0.2,
@@ -445,7 +386,7 @@ class Cluster:
         self.policy = policy
         # admission knobs (PR 5): ``admission=False`` restores the PR-3
         # always-grant behavior (writers never throttle, placement never
-        # re-routes) — the benchmark baseline. The deadline bounds how long
+        # re-routes) — the tests' baseline. The deadline bounds how long
         # the scheduler waits for a refusing node before diverting a
         # reducer; the timeout bounds how long a paced writer waits for a
         # staging grant before it is forced through.
@@ -464,9 +405,6 @@ class Cluster:
         self._pagelog_fsync = pagelog_fsync
         # amplification threshold for background log compaction (None = off)
         self._pagelog_compact_threshold = pagelog_compact_threshold
-        # warm the dispatch-plan kernel at boot so the first map batch is
-        # not charged with resolving (and possibly importing jax for) it
-        _resolve_dispatch_plan()
         # stats must exist before the nodes: every node's page log stamps
         # its records with the cluster's topology/job event counter
         self.stats = StatisticsDB()
@@ -495,7 +433,6 @@ class Cluster:
         # the revival fence treats registered blobs as valid log state.
         self.durable_blobs: Dict[str, Tuple[int, int]] = {}
         self.scheduler = ClusterScheduler(self)
-        self._transfer_workers = transfer_workers
         self._transfer: Optional[TransferEngine] = None
         self._acct_lock = tracked_lock("cluster.acct")
         self.net_bytes = 0          # bytes that crossed node boundaries
@@ -549,7 +486,7 @@ class Cluster:
         stamped into every log record at write time). ``warm=False`` models
         losing the machine's disk along with it: the log directory is wiped
         before the pool reopens, so recovery must pull every byte from
-        replicas — the cold baseline the benchmark measures against.
+        replicas — the cold baseline.
         Returns the fenced (purged) set names."""
         node = self.nodes[node_id]
         if node.alive:
@@ -624,7 +561,7 @@ class Cluster:
             cap = (derive_staging_cap(self.node_capacity,
                                       self.pressure_watermark)
                    if self.admission else None)
-            self._transfer = TransferEngine(self._transfer_workers,
+            self._transfer = TransferEngine(TRANSFER_WORKERS,
                                             name="transfer",
                                             dest_inflight_cap=cap)
         return self._transfer
@@ -1386,7 +1323,7 @@ class Cluster:
                               columnar=columnar, partition_fn=partition_fn)
 
     def shutdown(self) -> None:
-        """Stop the transfer engine's workers (benchmarks that build many
+        """Stop the transfer engine's workers (drivers that build many
         clusters call this; tests can rely on idle-exit instead)."""
         if self._transfer is not None:
             self._transfer.shutdown()
@@ -1557,11 +1494,10 @@ class ClusterShuffle:
 
     def map_columns(self, node_id: int, columns: Dict[str, np.ndarray],
                     n: int, keys: np.ndarray) -> None:
-        """Columnar map hot path: one fused hash-partition + gather +
-        incremental-CRC pass (``kernels.shuffle_dispatch.host_partition_crc``
-        when importable, ``core.columnar.fused_partition_crc`` otherwise)
-        routes a column batch, then each partition's contiguous column slice
-        is memcpy'd into that reducer's column blocks — no row
+        """Columnar map hot path: the reducer hash and :func:`dispatch_plan`
+        group a column batch by partition, and ``add_gathered`` gathers each
+        partition's rows straight into that reducer's column blocks with the
+        per-field CRC chains run over the landed bytes — no row
         materialization anywhere on the map side. ``keys`` is the (view of
         the) key column the reducer hash runs over; a ``partition_fn``
         override (the join path's scheme routing) takes the unfused
@@ -1579,10 +1515,9 @@ class ClusterShuffle:
                 # reducer hash -> narrow ids -> dispatch plan, then gather
                 # each partition's rows STRAIGHT into its landing pages
                 # (np.take with the page region as out) with the per-field
-                # CRC chains run over the landed bytes — the fused pass with
-                # zero intermediate copies (the ``fused_partition_crc``
-                # kernel materializing a routed block serves the non-landing
-                # callers and the roofline bench)
+                # CRC chains run over the landed bytes — zero intermediate
+                # copies (``core.columnar.fused_partition_crc``, which
+                # materializes a routed block, is the tests' reference)
                 h = route_partition_ids(keys, self.num_reducers)
                 parts = (h.astype(np.uint8) if self.num_reducers <= 256
                          else h.astype(np.int64))

@@ -56,14 +56,27 @@ from ..core.services import (ColumnarShuffleService, SequentialWriter,
 from ..core.shm_arena import (ArenaFullError, ShmArena, arena_name, gather,
                               segment_exists)
 from ..core.statistics import StatisticsDB
-from .cluster import (Cluster, DeadNodeError, RecoveryReport, ShardInfo,
-                      ShardedSet, StorageNode, _iter_record_chunks,
-                      _resolve_dispatch_plan, dispatch_plan, reducer_hash)
+from .cluster import (TRANSFER_WORKERS, Cluster, DeadNodeError,
+                      RecoveryReport, ShardInfo, ShardedSet, StorageNode,
+                      _iter_record_chunks, dispatch_plan, reducer_hash)
 from .rpc import RpcConnection, serve_connection
 from .scheduler import ClusterScheduler
 from .transfer import TransferEngine
 
 __all__ = ["ProcCluster", "ProcShuffle", "NodeDiedError", "CleanupReport"]
+
+
+# Each node's inbox and outbox shared-memory arena: its size, and the frame
+# granularity (one page image occupies ``ceil(nbytes / frame)`` frames).
+# Payloads that cannot get frames fall back to raw socket bytes, unpickled.
+ARENA_BYTES = 8 << 20
+ARENA_FRAME_BYTES = 1 << 16
+ARENA_FRAMES = ARENA_BYTES // ARENA_FRAME_BYTES
+# Driver->node record-shipping chunk: one arena put and one RPC each.
+RPC_CHUNK_BYTES = 1 << 20
+# Socket timeout on control-plane calls; an unresponsive node process reads
+# as dead (``DeadNodeError``) and trips the recovery paths.
+RPC_TIMEOUT_S = 60.0
 
 
 class NodeDiedError(DeadNodeError):
@@ -135,11 +148,10 @@ class _NodeServer:
             epoch_fn=lambda: self.epoch,
             pagelog_fsync=cfg["pagelog_fsync"],
             pagelog_compact_threshold=cfg.get("pagelog_compact_threshold"))
-        frame = int(cfg["frame_size"])
-        self.inbox = ShmArena.attach(cfg["inbox"], frame,
-                                     int(cfg["inbox_frames"]))
-        self.outbox = ShmArena.attach(cfg["outbox"], frame,
-                                      int(cfg["outbox_frames"]), owner=True)
+        self.inbox = ShmArena.attach(cfg["inbox"], ARENA_FRAME_BYTES,
+                                     ARENA_FRAMES)
+        self.outbox = ShmArena.attach(cfg["outbox"], ARENA_FRAME_BYTES,
+                                      ARENA_FRAMES, owner=True)
         self.admission = bool(cfg["admission"])
         self.timeout_s = float(cfg["admission_timeout_s"])
         self._peers: Dict[str, ShmArena] = {}
@@ -755,7 +767,7 @@ class _ChildShuffle:
     def reduce_stats(self, reducer: int) -> dict:
         """Count + order-independent content checksum of the landed reduce
         input, computed here so checksum-only verification never ships the
-        records anywhere (the benchmark's byte-identity certificate)."""
+        records anywhere (a byte-identity certificate)."""
         n = 0
         content = 0
         for chunk in self._reduce_chunks(reducer):
@@ -940,10 +952,10 @@ class ProcNodeHandle:
         g = self.generation
         self.generation += 1
         self.inbox = ShmArena(arena_name(f"in{self.node_id}g{g}"),
-                              c.arena_frame_bytes, c._inbox_frames,
+                              ARENA_FRAME_BYTES, ARENA_FRAMES,
                               create=True, owner=True)
         self.outbox = ShmArena(arena_name(f"out{self.node_id}g{g}"),
-                               c.arena_frame_bytes, c._outbox_frames,
+                               ARENA_FRAME_BYTES, ARENA_FRAMES,
                                create=True, owner=False)
         c._segments.extend([self.inbox.name, self.outbox.name])
         parent_sock, child_sock = socket.socketpair()
@@ -956,11 +968,8 @@ class ProcNodeHandle:
             "pagelog_dir": c._node_pagelog_dir(self.node_id),
             "pagelog_fsync": c._pagelog_fsync,
             "pagelog_compact_threshold": c._pagelog_compact_threshold,
-            "frame_size": c.arena_frame_bytes,
             "inbox": self.inbox.name,
-            "inbox_frames": c._inbox_frames,
             "outbox": self.outbox.name,
-            "outbox_frames": c._outbox_frames,
             "admission": c.admission,
             "admission_timeout_s": c.admission_timeout_s,
             "epoch": c.stats.event_seq,
@@ -972,7 +981,7 @@ class ProcNodeHandle:
             name=f"pangea-node{self.node_id}", daemon=True)
         self.proc.start()
         child_sock.close()
-        self.conn = RpcConnection(parent_sock, timeout_s=c.rpc_timeout_s)
+        self.conn = RpcConnection(parent_sock, timeout_s=RPC_TIMEOUT_S)
         self.set_mirror.clear()
         self.alive = True
         self.call("ping")
@@ -1048,18 +1057,14 @@ class ProcCluster:
     def __init__(self, num_nodes: int, node_capacity: int = 32 << 20,
                  page_size: int = 1 << 18, replication_factor: int = 1,
                  spill_dir: Optional[str] = None,
-                 transfer_workers: int = 4, policy: str = "data-aware",
+                 policy: str = "data-aware",
                  admission: bool = True,
                  admission_deadline_s: float = 0.05,
                  admission_timeout_s: float = 0.2,
                  pressure_watermark: float = 0.85,
                  pagelog_dir: Optional[str] = None,
                  pagelog_fsync: str = "none",
-                 pagelog_compact_threshold: Optional[float] = None,
-                 arena_bytes: int = 8 << 20,
-                 arena_frame_bytes: int = 1 << 16,
-                 rpc_chunk_bytes: int = 1 << 20,
-                 rpc_timeout_s: float = 60.0):
+                 pagelog_compact_threshold: Optional[float] = None):
         if num_nodes < 2:
             raise ValueError("a cluster needs at least 2 nodes")
         self.num_nodes = num_nodes
@@ -1075,21 +1080,12 @@ class ProcCluster:
         self._pagelog_dir = pagelog_dir
         self._pagelog_fsync = pagelog_fsync
         self._pagelog_compact_threshold = pagelog_compact_threshold
-        self.arena_frame_bytes = int(arena_frame_bytes)
-        self._inbox_frames = max(4, int(arena_bytes) // self.arena_frame_bytes)
-        self._outbox_frames = self._inbox_frames
-        self.rpc_chunk_bytes = int(rpc_chunk_bytes)
-        self.rpc_timeout_s = float(rpc_timeout_s)
         # Fork, even from a driver that already holds the TPU: a node process
-        # runs numpy host code only (the node server reaches no JAX call;
-        # the dispatch plan resolved below is numpy too), so it never
-        # initialises a JAX backend or touches the chip's runtime, and
-        # exits through os._exit without running the driver's teardown.
+        # runs numpy host code only (the node server reaches no JAX call),
+        # so it never initialises a JAX backend or touches the chip's
+        # runtime, and exits through os._exit without running the driver's
+        # teardown.
         self._ctx = mp.get_context("fork")  # spawn would re-import the world
-        # Resolve the dispatch-plan kernel BEFORE the first fork: children
-        # inherit the loaded module, instead of each paying the (possibly
-        # jax-sized) import serially inside their first map call.
-        _resolve_dispatch_plan()
         self.stats = StatisticsDB()
         self._segments: List[str] = []
         self.nodes: Dict[int, ProcNodeHandle] = {}
@@ -1100,7 +1096,6 @@ class ProcCluster:
         self.conflict_guards: Dict = {}
         self.durable_blobs: Dict[str, Tuple[int, int]] = {}
         self.scheduler = ClusterScheduler(self)
-        self._transfer_workers = transfer_workers
         self._transfer: Optional[TransferEngine] = None
         self._acct_lock = tracked_lock("proc.acct")
         self.net_bytes = 0
@@ -1148,7 +1143,7 @@ class ProcCluster:
             cap = (derive_staging_cap(self.node_capacity,
                                       self.pressure_watermark)
                    if self.admission else None)
-            self._transfer = TransferEngine(self._transfer_workers,
+            self._transfer = TransferEngine(TRANSFER_WORKERS,
                                             name="transfer",
                                             dest_inflight_cap=cap)
         return self._transfer
@@ -1224,7 +1219,7 @@ class ProcCluster:
         fallback).  Returns the record bytes shipped."""
         handle = self.node(node_id)
         payload = records.tobytes()
-        chunk = self.rpc_chunk_bytes
+        chunk = RPC_CHUNK_BYTES
         offsets = list(range(0, len(payload), chunk)) or [0]
         for i, off in enumerate(offsets):
             piece = payload[off:off + chunk]
@@ -1250,7 +1245,7 @@ class ProcCluster:
         cursor = None
         while True:
             fields = {"name": set_name, "dtype": _dtype_to_wire(dtype),
-                      "max_bytes": self.rpc_chunk_bytes}
+                      "max_bytes": RPC_CHUNK_BYTES}
             if cursor is not None:
                 fields["cursor"] = cursor
             rep, raw = handle.call("export_set", **fields)
@@ -1274,7 +1269,7 @@ class ProcCluster:
         cursor = None
         while True:
             fields = {"name": src_set, "dtype": _dtype_to_wire(dtype),
-                      "max_bytes": self.rpc_chunk_bytes}
+                      "max_bytes": RPC_CHUNK_BYTES}
             if cursor is not None:
                 fields["cursor"] = cursor
             rep, raw = src.call("export_set", **fields)
@@ -1758,7 +1753,7 @@ class ProcShuffle:
         while True:
             rep, raw = src.call("export_part", shuffle=self.name,
                                 reducer=reducer,
-                                max_bytes=self.cluster.rpc_chunk_bytes)
+                                max_bytes=RPC_CHUNK_BYTES)
             desc = rep.get("desc")
             fields = {"shuffle": self.name, "reducer": reducer,
                       "src_node": src_id, "sizes": rep["sizes"],
@@ -1830,7 +1825,7 @@ class ProcShuffle:
         cursor = None
         while True:
             fields = {"shuffle": self.name, "reducer": reducer,
-                      "max_bytes": self.cluster.rpc_chunk_bytes}
+                      "max_bytes": RPC_CHUNK_BYTES}
             if cursor is not None:
                 fields["cursor"] = cursor
             rep, raw = dst.call("reduce_read", **fields)

@@ -339,7 +339,7 @@ class ClusterScheduler:
     def placement_net_bytes(self, shuffle_name: str,
                             placement: Dict[int, int]) -> int:
         """Predicted cross-node bytes for a reducer placement (what the
-        benchmark reports next to the measured figure)."""
+        tests compare with the measured figure)."""
         stats = self.cluster.stats
         total = 0
         for r, node in placement.items():

@@ -1,7 +1,7 @@
 """Columnar page layout (PR 7): block-format roundtrip, checksum
 byte-compatibility with the row scheme, per-field CRC chain invariance,
-the fused dispatch-plan kernel vs its host fallback (the PR-7 resolution
-bugfix), the zero-intermediate gather landing, cluster-level byte identity
+the dispatch plan's grouping contract, the zero-intermediate gather
+landing against the fused reference pass, cluster-level byte identity
 (including the over-capacity spill path and pull verification flags), the
 shuffle -> aggregate -> join property sweep, and the pagelog fsync policy
 knob."""
@@ -22,9 +22,7 @@ from repro.core.pagelog import FSYNC_POLICIES, PageLog, fsck
 from repro.core.replication import record_content_checksum
 from repro.core.services import canonical_join_sort, columnar_job_data_attrs
 from repro.runtime.cluster import (Cluster, ClusterShuffle,
-                                   _host_dispatch_plan,
-                                   cluster_hash_aggregate, dispatch_impl,
-                                   dispatch_plan)
+                                   cluster_hash_aggregate, dispatch_plan)
 from repro.runtime.join import cluster_join
 
 REC = np.dtype([("key", np.int64), ("payload", np.uint8, (10,))])
@@ -94,36 +92,30 @@ def test_per_field_crc_chains_invariant_to_splits():
         assert crcs == whole
 
 
-# -- fused dispatch plan (PR-7 resolution bugfix) -----------------------------
-def test_dispatch_plan_resolves_kernel_path_once():
-    """The ImportError used to be swallowed per call, silently pinning the
-    host fallback; the resolution is now cached and observable. This
-    container ships jax, so the kernel package must win."""
-    assert dispatch_impl() == "kernels.shuffle_dispatch"
-    assert dispatch_impl() == "kernels.shuffle_dispatch"  # cached
-
-
+# -- dispatch plan ------------------------------------------------------------
 @pytest.mark.parametrize("case", ["random", "empty", "single_partition",
-                                  "all_same_key"])
-def test_kernel_plan_matches_host_plan(case):
+                                  "all_same_key", "trailing_empty"])
+def test_dispatch_plan_groups_rows_by_partition(case):
     rng = np.random.default_rng(7)
-    parts = {
-        "random": rng.integers(0, 16, 5000).astype(np.uint8),
-        "empty": np.zeros(0, np.uint8),
-        "single_partition": np.full(4096, 3, np.uint8),
-        "all_same_key": np.zeros(100, np.uint8),
+    parts, num_partitions = {
+        "random": (rng.integers(0, 16, 5000).astype(np.uint8), 16),
+        "empty": (np.zeros(0, np.uint8), 16),
+        "single_partition": (np.full(4096, 3, np.uint8), 16),
+        "all_same_key": (np.zeros(100, np.uint8), 16),
+        # more partitions than the largest id: the trailing ones are empty
+        "trailing_empty": (rng.integers(0, 5, 1000), 40),
     }[case]
-    from repro.kernels.shuffle_dispatch.ops import host_dispatch_plan
-    got = host_dispatch_plan(parts, 16)
-    want = _host_dispatch_plan(parts, 16)
-    for g, w in zip(got, want):
-        assert np.array_equal(g, w)
-    order, counts, offsets = got
-    assert counts.sum() == len(parts) and offsets[-1] == len(parts)
-    # the plan really groups: every slice holds exactly its partition's rows
-    for p in range(16):
+    order, counts, offsets = dispatch_plan(parts, num_partitions)
+    assert len(counts) == num_partitions
+    assert len(offsets) == num_partitions + 1
+    assert offsets[0] == 0 and offsets[-1] == len(parts)
+    assert sorted(order.tolist()) == list(range(len(parts)))
+    for p in range(num_partitions):
         sl = order[offsets[p]:offsets[p + 1]]
+        assert counts[p] == len(sl) == np.count_nonzero(parts == p)
+        # every slice holds exactly its partition's rows, in input order
         assert np.all(parts[sl] == p)
+        assert np.all(np.diff(sl) > 0)
 
 
 def test_gather_landing_matches_fused_reference():
