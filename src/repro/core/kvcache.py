@@ -10,9 +10,10 @@ buffer pool:
 * Eq. 1 orders sequences for eviction: finished sequences (lifetime-ended)
   first, then cold sequences (stale ``t_r``), exactly the paper's dynamic
   priority;
-* evicted pages are offloaded HBM→host (on this CPU container: a numpy store;
-  on TPU: ``jax.device_put(..., memory_kind="pinned_host")``) and restored on
-  demand.
+* evicted pages are offloaded HBM→host as numpy slabs and restored on
+  demand, in batches: the pages one eviction frees leave the pool in one
+  gather and one device→host copy; the pages one restore brings back enter
+  in one host→device copy and one scatter into the donated pool.
 
 The host side is pluggable: ``HostSlabStore`` is the flat dict default, and
 ``runtime/serving.py`` substitutes a tiered store that charges the node's
@@ -23,6 +24,7 @@ The device half (attention over the page pool) is ``kernels/paged_attention``.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -44,6 +46,25 @@ def kv_attrs() -> AttributeSet:
 
 class HBMExhaustedError(MemoryError):
     pass
+
+
+@functools.lru_cache(maxsize=None)
+def _slot_programs():
+    """The jitted page movers, built on first use so that importing this
+    module needs no JAX. ``gather_slots(kv, slots)`` copies the pages at
+    ``slots`` out as ``[n, L, page, *token_shape]``; ``scatter_slots(kv,
+    slots, batch)`` writes such a batch into the pool it consumes (donated,
+    so the pool is updated in place and not copied whole)."""
+    import jax
+    import jax.numpy as jnp
+
+    def gather_slots(kv, slots):
+        return jnp.moveaxis(kv[:, slots], 1, 0)
+
+    def scatter_slots(kv, slots, batch):
+        return kv.at[:, slots].set(jnp.moveaxis(batch, 0, 1))
+
+    return jax.jit(gather_slots), jax.jit(scatter_slots, donate_argnums=0)
 
 
 class HostSlabStore:
@@ -130,7 +151,10 @@ class PagedKVCache:
         self._pages: Dict[int, Page] = {}
         self.host_store = host_store if host_store is not None else HostSlabStore()
         self._next_page_id = 0
-        self.stats = {"offloads": 0, "fetches": 0, "offload_bytes": 0}
+        # offloads and fetches count pages; the *_batches count the gathers
+        # and scatters that moved them
+        self.stats = {"offloads": 0, "fetches": 0, "offload_bytes": 0,
+                      "offload_batches": 0, "restore_batches": 0}
 
     @property
     def slab_nbytes(self) -> int:
@@ -168,45 +192,96 @@ class PagedKVCache:
         self.paging.unregister(ls.name)
 
     # -- page management ----------------------------------------------------------
-    def _evict_one(self) -> None:
-        picked = self.paging.pick_victims(self.clock)
-        if picked is None:
-            raise HBMExhaustedError("all KV pages pinned (every sequence active)")
-        ls, victims = picked
-        for vp in victims:
-            self._offload(vp)
+    def _padded(self, slots: List[int]) -> np.ndarray:
+        """``slots`` padded with its last slot to the next power of two, or
+        to the pool's page count if that is smaller: a process compiles at
+        most one gather and one scatter per such size."""
+        size = min(1 << (len(slots) - 1).bit_length(), self.hbm_pages)
+        return np.asarray(slots + slots[-1:] * (size - len(slots)), np.int32)
 
-    def _offload(self, page: Page) -> None:
+    def _evict(self, n: int, exclude_set: Optional[str] = None) -> None:
+        """Free at least ``n`` slots: detach Eq.-1 victims pick by pick
+        (bookkeeping only; a detached page is no longer resident, so no
+        later pick returns it), then offload them all in one batch. The set
+        named ``exclude_set`` gives no victims."""
+        victims: List[Tuple[Page, int]] = []
+        while len(victims) < n:
+            picked = self.paging.pick_victims(self.clock, exclude=exclude_set)
+            if picked is None:
+                for page, slot in victims:
+                    page.offset = slot
+                raise HBMExhaustedError(
+                    "all KV pages pinned (every sequence active)")
+            for page in picked[1]:
+                victims.append((page, page.offset))
+                page.offset = None
+        self._offload(victims)
+
+    def _offload(self, victims: List[Tuple[Page, int]]) -> None:
+        """Copy the victims' slabs to the host store: one gather of their
+        slots, one device→host copy, one slab a page. A page whose slab the
+        store did not take keeps its slot, so a refusing store loses
+        nothing."""
         import jax
-        assert page.offset is not None
+        gather, _ = _slot_programs()
+        slots = [slot for _page, slot in victims]
+        done = 0
         with jax.profiler.TraceAnnotation("kvcache.offload",
-                                          page=page.page_id):
-            # device -> host (CPU container: numpy copy of that page's slab)
-            slab = np.asarray(self.kv[:, page.offset])
-            self.host_store.put(page.page_id, slab)
-        self.stats["offloads"] += 1
-        self.stats["offload_bytes"] += slab.nbytes
-        self._free_slots.append(page.offset)
-        page.offset = None
+                                          pages=len(victims)):
+            try:
+                rows = np.asarray(gather(self.kv, self._padded(slots)))
+                for page, _slot in victims:
+                    # a copy of its row: the slab owns exactly its bytes
+                    self.host_store.put(page.page_id, rows[done].copy())
+                    done += 1
+            finally:
+                for page, slot in victims[done:]:
+                    page.offset = slot
+                self._free_slots.extend(slots[:done])
+                self.stats["offloads"] += done
+                self.stats["offload_bytes"] += done * self.slab_nbytes
+                self.stats["offload_batches"] += 1
 
-    def _restore(self, seq_id: int, page: Page, ls: LocalitySet) -> int:
+    def _restore(self, seq_id: int, pages: List[Page],
+                 exclude_set: Optional[str] = None) -> None:
+        """Bring offloaded ``pages`` back into HBM: slots for all of them in
+        one allocation, then one host→device copy and one scatter of the
+        slabs the host store held. If a ``take`` raises, the slabs already
+        taken are written and the slots not used go back before it
+        propagates."""
         import jax
         with jax.profiler.TraceAnnotation("kvcache.restore", seq=seq_id,
-                                          page=page.page_id):
-            slot = self._alloc_slot(exclude_set=ls.name)
+                                          pages=len(pages)):
+            slots = self._alloc_slots(len(pages), exclude_set)
+            taken: List[Tuple[Page, int, Optional[np.ndarray]]] = []
             try:
-                slab = self.host_store.take(page.page_id)
-            except BaseException:
-                # a tiered store may fail mid-fetch (dead remote node); the
-                # slot must go back so the cache stays consistent for the
-                # retry
-                self._free_slots.append(slot)
-                raise
-            if slab is not None:
-                self._write_slot(seq_id, slot, slab)
-                self.stats["fetches"] += 1
-            page.offset = slot
-        return slot
+                for page, slot in zip(pages, slots):
+                    slab = self.host_store.take(page.page_id)
+                    taken.append((page, slot, slab))
+            finally:
+                self._free_slots.extend(slots[len(taken):])
+                found = [(slot, slab) for _p, slot, slab in taken
+                         if slab is not None]
+                if found:
+                    self._write_slots(seq_id, [f[0] for f in found],
+                                      [f[1] for f in found])
+                for page, slot, _slab in taken:
+                    page.offset = slot
+                self.stats["fetches"] += len(found)
+                self.stats["restore_batches"] += 1
+
+    def _write_slots(self, seq_id: int, slots: List[int],
+                     slabs: List[np.ndarray]) -> None:
+        """Write ``slabs`` into ``slots``: one host→device copy and one
+        scatter that consumes the pool. Padding repeats the last slot with
+        its own slab."""
+        import jax
+        _, scatter = _slot_programs()
+        idx = self._padded(slots)
+        with jax.profiler.TraceAnnotation("kvcache.pool_write", seq=seq_id):
+            batch = np.stack(slabs + slabs[-1:] * (len(idx) - len(slabs)))
+            self.kv = scatter(self.kv, idx,
+                              jax.device_put(batch, self.device))
 
     def _write_slot(self, seq_id: int, slot: int, slab: np.ndarray) -> None:
         import jax
@@ -214,17 +289,20 @@ class PagedKVCache:
             self.kv = self.kv.at[:, slot].set(
                 jax.device_put(slab, self.device))
 
-    def _alloc_slot(self, exclude_set: Optional[str] = None) -> int:
-        while not self._free_slots:
-            self._evict_one()
-        return self._free_slots.pop()
+    def _alloc_slots(self, n: int,
+                     exclude_set: Optional[str] = None) -> List[int]:
+        """``n`` free slots, evicting in one batch what the free list lacks;
+        no page of the set named ``exclude_set`` is evicted for them."""
+        if len(self._free_slots) < n:
+            self._evict(n - len(self._free_slots), exclude_set)
+        return [self._free_slots.pop() for _ in range(n)]
 
     def append_page(self, seq_id: int) -> Page:
         """Allocate the next logical page for a sequence."""
         st = self._seqs[seq_id]
         ls = self._sets[seq_id]
         self.clock += 1
-        slot = self._alloc_slot()
+        slot = self._alloc_slots(1)[0]
         page = Page(page_id=self._next_page_id, set_name=ls.name,
                     size=self.page_size, offset=slot, pin_count=0, dirty=True,
                     last_access=self.clock)
@@ -248,10 +326,16 @@ class PagedKVCache:
         self.clock += 1
         ls.set_operation(CurrentOperation.READ_AND_WRITE, self.clock)
         table = np.full(max_pages, -1, dtype=np.int32)
-        for i, pid in enumerate(st.page_ids[:max_pages]):
-            page = self._pages[pid]
+        pages = [self._pages[pid] for pid in st.page_ids[:max_pages]]
+        offloaded = [p for p in pages if p.offset is None]
+        if offloaded and len(pages) <= self.hbm_pages:
+            # one batch, whose slots never come from the pages listed here
+            self._restore(seq_id, offloaded, exclude_set=ls.name)
+        for i, page in enumerate(pages):
             if page.offset is None:
-                self._restore(seq_id, page, ls)
+                # more pages than the pool: no table holds them all at once;
+                # each comes back in turn and may push out earlier ones
+                self._restore(seq_id, [page])
             page.last_access = self.clock
             table[i] = page.offset
         return table
@@ -264,11 +348,12 @@ class PagedKVCache:
         """Overwrite one logical page's slab ``[L, page, *token_shape]``;
         restores the page to HBM first if it was offloaded."""
         st = self._seqs[seq_id]
-        ls = self._sets[seq_id]
         page = self._pages[st.page_ids[page_index]]
         self.clock += 1
         if page.offset is None:
-            self._restore(seq_id, page, ls)
+            # no block table is held here, so any page, the sequence's own
+            # included, may give up its slot (a prefill larger than the pool)
+            self._restore(seq_id, [page])
         page.last_access = self.clock
         page.dirty = True
         self._write_slot(seq_id, page.offset, slab)

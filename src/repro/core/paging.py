@@ -105,14 +105,16 @@ class PagingSystem:
             self._push(ls)
 
     # -- Algorithm 1 -----------------------------------------------------------
-    def pick_victims(self, clock: int) -> Optional[Tuple[LocalitySet, List[Page]]]:
+    def pick_victims(self, clock: int, exclude: Optional[str] = None
+                     ) -> Optional[Tuple[LocalitySet, List[Page]]]:
         """Returns (victim set, victim pages) or None if nothing evictable.
+        The set named ``exclude`` never supplies victims.
 
         Lazy-heap walk: skip stale entries and sets with no unpinned resident
         pages; re-push skipped-but-live sets so they stay in the queue.
         """
         if self.policy in ("lru", "mru"):
-            return self._pick_global_recency(self.policy)
+            return self._pick_global_recency(self.policy, exclude)
         repush: List[LocalitySet] = []
         found = None
         while self._heap:
@@ -120,7 +122,7 @@ class PagingSystem:
             ls = self._sets.get(name)
             if ls is None or self._stale.get(name) != eid:
                 continue  # stale entry
-            victims = ls.select_victims()
+            victims = [] if name == exclude else ls.select_victims()
             if victims:
                 found = (ls, victims)
                 repush.append(ls)
@@ -130,12 +132,14 @@ class PagingSystem:
             self._push(ls)
         return found
 
-    def _pick_global_recency(self, policy: str):
+    def _pick_global_recency(self, policy: str, exclude: Optional[str]):
         """Fig.-3/8/9 baselines: 10% of unpinned pages by global recency,
         ignoring data semantics. Victims are grouped under their owning set
         (one set per call — the caller loops)."""
         pages: List[Tuple[int, LocalitySet, Page]] = []
         for ls in self._sets.values():
+            if ls.name == exclude:
+                continue
             for p in ls.unpinned_resident_pages():
                 pages.append((p.last_access, ls, p))
         if not pages:

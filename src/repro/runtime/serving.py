@@ -172,7 +172,8 @@ class TieredSlabStore(HostSlabStore):
         self._lock = tracked_rlock("serving.slabstore")
         self._local: Dict[int, Tuple[np.ndarray, object]] = {}
         self._order: List[int] = []          # FIFO overflow order
-        self._inflight: Dict[int, Tuple[object, int]] = {}
+        # pid -> (future, target, the slab it ships)
+        self._inflight: Dict[int, Tuple[object, int, np.ndarray]] = {}
         self._remote: Dict[int, int] = {}    # pid -> level-3 holder node
         self.host_bytes = 0
         self.stats = {"remote_spills": 0, "remote_fetches": 0,
@@ -299,7 +300,7 @@ class TieredSlabStore(HostSlabStore):
             self._ship, page_id, target, slab,
             label=f"kvspill:{self.node_id}:{page_id}",
             dest=target, nbytes=slab.nbytes)
-        self._inflight[page_id] = (fut, target)
+        self._inflight[page_id] = (fut, target, slab)
         return True
 
     def _ship(self, page_id: int, target: int, slab: np.ndarray) -> int:
@@ -310,12 +311,12 @@ class TieredSlabStore(HostSlabStore):
 
     def _reap(self) -> None:
         with self._lock:
-            done = [(pid, fut, target)
-                    for pid, (fut, target) in self._inflight.items()
+            done = [(pid, fut, target, slab)
+                    for pid, (fut, target, slab) in self._inflight.items()
                     if fut.done()]
-            for pid, _fut, _target in done:
+            for pid, _fut, _target, _slab in done:
                 del self._inflight[pid]
-        for pid, fut, target in done:
+        for pid, fut, target, shipped in done:
             try:
                 fut.result(timeout=0)
             except Exception:
@@ -324,12 +325,17 @@ class TieredSlabStore(HostSlabStore):
                 self.stats["spill_failures"] += 1
                 continue
             with self._lock:
-                entry = self._local.pop(pid, None)
-                if entry is not None:
+                entry = self._local.get(pid)
+                if entry is not None and entry[0] is shipped:
+                    del self._local[pid]
                     self._order.remove(pid)
                     self.host_bytes -= entry[0].nbytes
                     self._remote[pid] = target
-            if entry is None:     # taken/discarded while the copy flew
+                else:
+                    entry = None
+            if entry is None:
+                # taken, discarded or put anew while the copy flew: the
+                # remote copy is stale; a newer local slab ships later
                 self.tier.cluster.drop_bytes(target, self._blob(pid))
                 continue
             slab, res = entry
@@ -341,7 +347,7 @@ class TieredSlabStore(HostSlabStore):
         """Release every charge and drop every level-3 blob (remote blobs
         live on *other* nodes, so this works even when the home node is
         dead — failover cleanup rides it)."""
-        for pid, (fut, _t) in list(self._inflight.items()):
+        for pid, (fut, _t, _slab) in list(self._inflight.items()):
             try:
                 fut.result(timeout=5.0)
             except Exception:

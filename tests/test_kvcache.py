@@ -3,7 +3,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import HBMExhaustedError, PagedKVCache
+from repro.core import HBMExhaustedError, HostSlabStore, PagedKVCache
 from repro.kernels.paged_attention.ops import paged_attention
 
 
@@ -149,3 +149,126 @@ def test_attention_noncontiguous_pages_after_evict_restore():
     for k in range(3):
         assert kv.read_page(0, k).tobytes() == before[k].tobytes()
     assert np.isfinite(out).all()
+
+
+# -- batched page movement: one gather per eviction, one scatter per restore --
+TOKEN_SHAPES = {"kv": (2, 2, 4), "latent": (576,)}
+
+
+class _FlakyStore(HostSlabStore):
+    """Raises on its ``fail_at``-th ``take`` (a dead level-3 holder)."""
+
+    def __init__(self):
+        super().__init__()
+        self.fail_at = None
+        self.takes = 0
+
+    def take(self, page_id):
+        self.takes += 1
+        if self.takes == self.fail_at:
+            raise RuntimeError("holder died mid-restore")
+        return super().take(page_id)
+
+
+def _batch_cache(token_shape, store=None):
+    return PagedKVCache(num_layers=2, hbm_pages=8, page_size=4,
+                        token_shape=token_shape, host_store=store)
+
+
+def _fill(kv, seq, pages, rng, written):
+    kv.start_sequence(seq)
+    kv.ensure_capacity(seq, pages * kv.page_size)
+    kv.advance(seq, pages * kv.page_size)
+    for k in range(pages):
+        slab = rng.standard_normal(
+            (kv.num_layers, kv.page_size) + kv.token_shape).astype(np.float32)
+        kv.write_page(seq, k, slab)
+        written[seq, k] = slab
+
+
+def _assert_intact(kv, written):
+    """Every page reads back its bytes, and the free list and the resident
+    pages share the pool's slots between them, each slot once."""
+    for (seq, k), slab in written.items():
+        assert kv.read_page(seq, k).tobytes() == slab.tobytes(), (seq, k)
+    held = [p.offset for p in kv._pages.values() if p.offset is not None]
+    slots = held + kv._free_slots
+    assert sorted(slots) == list(range(kv.hbm_pages))
+
+
+def _offloaded_cold_session(token_shape, store=None):
+    """Seq 0's five pages are all offloaded, one at a time, by seq 2; seq 1
+    (hot) and seq 2 hold the pool."""
+    kv = _batch_cache(token_shape, store)
+    rng, written = np.random.default_rng(3), {}
+    _fill(kv, 0, 5, rng, written)
+    _fill(kv, 1, 3, rng, written)
+    kv.block_table(1, 3)
+    _fill(kv, 2, 5, rng, written)
+    assert all(kv._pages[p].offset is None for p in kv._seqs[0].page_ids)
+    return kv, written
+
+
+@pytest.mark.parametrize("shape", sorted(TOKEN_SHAPES))
+def test_batched_round_trip_is_byte_identical_in_scattered_slots(shape):
+    """Five pages (no pad size) come back in one batch into slots freed by
+    one batched eviction of two other sessions' pages."""
+    kv, written = _offloaded_cold_session(TOKEN_SHAPES[shape])
+    offloads = kv.stats["offload_batches"]
+    table = kv.block_table(0, 5)
+    assert kv.stats["offload_batches"] == offloads + 1
+    assert len(set(table.tolist())) == 5
+    assert not (np.diff(np.sort(table)) == 1).all()     # not contiguous
+    for k, slot in enumerate(table):
+        assert np.asarray(kv.kv[:, slot]).tobytes() == written[0, k].tobytes()
+    _assert_intact(kv, written)
+
+
+@pytest.mark.parametrize("shape", sorted(TOKEN_SHAPES))
+def test_one_resume_is_one_restore_batch(shape):
+    kv, _written = _offloaded_cold_session(TOKEN_SHAPES[shape])
+    before = dict(kv.stats)
+    kv.block_table(0, 5)
+    assert kv.stats["restore_batches"] == before["restore_batches"] + 1
+    assert kv.stats["fetches"] == before["fetches"] + 5
+    assert kv.stats["offloads"] == before["offloads"] + 5
+
+
+@pytest.mark.parametrize("shape", sorted(TOKEN_SHAPES))
+def test_resuming_sessions_own_pages_are_never_victims(shape):
+    """Under global LRU the resuming session's resident pages are the
+    oldest in the pool; its restore still evicts only other sessions'."""
+    kv = _batch_cache(TOKEN_SHAPES[shape])
+    kv.paging.policy = "lru"
+    rng, written = np.random.default_rng(4), {}
+    _fill(kv, 0, 6, rng, written)
+    _fill(kv, 1, 2, rng, written)
+    _fill(kv, 2, 3, rng, written)            # pushes out 3 of seq 0's pages
+    pages = [kv._pages[p] for p in kv._seqs[0].page_ids]
+    resident = {p.page_id: p.offset for p in pages if p.offset is not None}
+    assert len(resident) == 3
+    offloads = kv.stats["offloads"]
+    table = kv.block_table(0, 6)
+    assert kv.stats["offloads"] == offloads + 3
+    assert {p.page_id: p.offset for p in pages
+            if p.page_id in resident} == resident
+    assert len(set(table.tolist())) == 6
+    _assert_intact(kv, written)
+
+
+@pytest.mark.parametrize("fail_at", [1, 3, 5])
+@pytest.mark.parametrize("shape", sorted(TOKEN_SHAPES))
+def test_take_that_raises_mid_batch_loses_nothing(shape, fail_at):
+    """The slabs taken before the failure are written, the slots not used
+    go back, and a retry restores the rest."""
+    store = _FlakyStore()
+    kv, written = _offloaded_cold_session(TOKEN_SHAPES[shape], store)
+    store.fail_at = store.takes + fail_at
+    with pytest.raises(RuntimeError, match="mid-restore"):
+        kv.block_table(0, 5)
+    back = [kv._pages[p].offset is not None for p in kv._seqs[0].page_ids]
+    assert sum(back) == fail_at - 1
+    _assert_intact(kv, written)
+    table = kv.block_table(0, 5)
+    assert len(set(table.tolist())) == 5
+    _assert_intact(kv, written)

@@ -3,6 +3,7 @@ sequences, continuous-batching admission, three-level spill, and the
 fault-injection sweep — SIGKILL/kill_node at every serving phase boundary,
 on both backends."""
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -219,6 +220,32 @@ def test_spill_target_death_mid_transfer_loses_nothing(tmp_path, layout):
     cluster.transfer.drain(timeout=10.0)
     store._reap()
     assert tier.verify(9)    # every slab still reachable, byte-identical
+    tier.close()
+    _assert_clean(cluster)
+    _teardown(cluster, "inproc")
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_slab_put_anew_while_its_spill_flies_comes_back_new(tmp_path,
+                                                           layout):
+    """A page taken back and offloaded again with new bytes while its
+    earlier level-3 copy is still in flight: once that copy lands, the
+    page must read back the new bytes, not the stale remote ones."""
+    cluster = _cluster("inproc", tmp_path)
+    tier = _tier(cluster, layout, host_budget_bytes=0)
+    store = tier._shard(0).store
+    gate = threading.Event()
+    tier.add_fault_hook("during_spill", lambda: gate.wait(10.0))
+    old = np.full(tier.slab_shape, 1, tier.dtype)
+    new = np.full(tier.slab_shape, 2, tier.dtype)
+    store.put(7, old)                       # its spill waits at the gate
+    assert store.take(7) is old
+    store.put(7, new)
+    gate.set()
+    cluster.transfer.drain(timeout=10.0)
+    store._reap()
+    assert store.peek(7).tobytes() == new.tobytes()
+    assert store.take(7).tobytes() == new.tobytes()
     tier.close()
     _assert_clean(cluster)
     _teardown(cluster, "inproc")

@@ -119,17 +119,28 @@ def test_spans_nest(traced, child, parent):
 
 def test_restores_that_found_a_slab_are_the_fetches(traced):
     (_outs, _slabs, fetches, _node), lines = traced
-    # a restore writes the pool only when the host tier held the slab; the
-    # window starts at the decode after admission
+    # a restore batch writes the pool only when the host tier held its
+    # slabs, and names its pages; the window starts at the decode after
+    # admission
     first = min(e[1] for e in _spans(lines, "memory.admission_wait"))
     found = 0
     for line in lines:
         writes = [e for e in line if e[0] == "kvcache.pool_write"]
-        found += sum(r[1] >= first and any(
-            r[1] <= w[1] and w[2] <= r[2] for w in writes)
-            for r in line if r[0] == "kvcache.restore")
+        found += sum(int(r[3]["pages"]) for r in line
+                     if r[0] == "kvcache.restore" and r[1] >= first
+                     and any(r[1] <= w[1] and w[2] <= r[2] for w in writes))
     assert fetches > 0
     assert found == fetches
+
+
+@pytest.mark.parametrize("name", ["kvcache.offload", "kvcache.restore"])
+def test_page_batches_carry_their_page_count(traced, name):
+    """Each offload and restore span is one batch of pages and says how
+    many; some batch moves more than one page."""
+    _result, lines = traced
+    counts = [int(e[3]["pages"]) for e in _spans(lines, name)]
+    assert counts and min(counts) >= 1
+    assert max(counts) > 1
 
 
 def test_spans_carry_node_and_session(traced):
